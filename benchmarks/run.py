@@ -138,6 +138,8 @@ def main(json_path: str | None = None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     json_path = None
     if "--json" in sys.argv:
         i = sys.argv.index("--json")

@@ -110,8 +110,6 @@ def _measured_bytes(fn, *args):
     exposes cost analysis (TPU does; CPU interpret mode may not)."""
     try:
         cost = jax.jit(fn).lower(*args).compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0]
         return float(cost["bytes accessed"])
     except Exception:
         return None

@@ -74,10 +74,11 @@ def seeded_gaussian(seed, tag: int, rows, cols):
     h = _fmix32(h ^ cols.astype(jnp.uint32))
     b1 = _fmix32(h ^ jnp.uint32(0x632BE59B))
     b2 = _fmix32(h ^ jnp.uint32(0x2545F491))
-    u1 = ((b1 >> jnp.uint32(8)).astype(jnp.float32) + jnp.float32(0.5)) \
-        * jnp.float32(2.0 ** -24)
-    u2 = ((b2 >> jnp.uint32(8)).astype(jnp.float32) + jnp.float32(0.5)) \
-        * jnp.float32(2.0 ** -24)
+    # the top 24 bits fit int32, and Mosaic converts only signed ints
+    u1 = ((b1 >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+          + jnp.float32(0.5)) * jnp.float32(2.0 ** -24)
+    u2 = ((b2 >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+          + jnp.float32(0.5)) * jnp.float32(2.0 ** -24)
     r = jnp.sqrt(jnp.float32(-2.0) * jnp.log(u1))
     return (r * jnp.cos(jnp.float32(2.0 * jnp.pi) * u2)).astype(jnp.float32)
 

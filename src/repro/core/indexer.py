@@ -58,10 +58,10 @@ class IndexConfig:
     lbh_lr: float = 0.03
     # EH dimension-sampling trick (paper §5.2); None = exact d^2 embedding
     eh_sample_dims: int | None = None
-    # route hashing/scans through the Pallas kernels; the default honours
-    # the REPRO_USE_KERNELS env var (CI's fallback leg sets it to 0)
-    use_kernels: bool = dataclasses.field(
-        default_factory=lambda: env_use_kernels(False))
+    # route hashing/scans through the Pallas kernels; the default follows
+    # the platform (on where the backend is a TPU) and the REPRO_USE_KERNELS
+    # env var overrides it (CI runs a leg with each value)
+    use_kernels: bool = dataclasses.field(default_factory=env_use_kernels)
     # fused-scan selection algorithm: "hist" (counting-sort select, cheap
     # at any scan depth l) or "argmin" (legacy l-round masked argmin — the
     # escape hatch).  None honours the REPRO_FUSED_SELECT env var (default

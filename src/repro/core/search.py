@@ -27,14 +27,16 @@ from repro.utils.bits import hamming_packed
 DIST_SENTINEL = 0x3FFFFFFF
 
 
-def env_use_kernels(default: bool) -> bool:
-    """Default for the use_kernel(s) knobs, overridable via the
-    ``REPRO_USE_KERNELS`` env var (CI runs a leg with it set to 0 so the
-    pure-jnp fallbacks stay exercised).  Explicit arguments always win —
-    the env var only moves the default."""
+def env_use_kernels() -> bool:
+    """Default for the use_kernel(s) knobs: the Pallas kernels run where
+    the default backend is a TPU, and the pure-jnp paths elsewhere (off the
+    TPU the kernels could only run in the Pallas interpreter).  The
+    ``REPRO_USE_KERNELS`` env var overrides the platform (CI runs legs with
+    it set to 1 and to 0 so both paths stay exercised on the CPU).
+    Explicit arguments always win — the env var only moves the default."""
     env = os.environ.get("REPRO_USE_KERNELS")
     if env is None or not env.strip():
-        return default
+        return jax.default_backend() == "tpu"
     return env.strip().lower() not in ("0", "false", "no", "off")
 
 
@@ -87,17 +89,6 @@ def _pad_topk(dists, ids, l: int):
     pad = [(0, 0)] * (dists.ndim - 1) + [(0, l - have)]
     return (jnp.pad(dists, pad, constant_values=DIST_SENTINEL),
             jnp.pad(ids, pad, constant_values=-1))
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """jax.shard_map (>= 0.5, `check_vma`) or the jax 0.4.x
-    jax.experimental.shard_map.shard_map (`check_rep`)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
 
 
 @partial(jax.jit, static_argnames=("l",))
@@ -358,7 +349,7 @@ def hamming_topk_sharded(codes, query, l: int, mesh, axis: str = "data",
     shards are contiguous row ranges gathered in shard order.
     """
     if use_kernel is None:
-        use_kernel = env_use_kernels(True)
+        use_kernel = env_use_kernels()
     select = env_fused_select(select)
     pack = env_cand_pack(pack)
     return _sharded_fn(mesh, axis, l, use_kernel, select, pack)(codes, query)
@@ -370,12 +361,13 @@ def _sharded_fn(mesh, axis: str, l: int, use_kernel: bool, select: str,
     """Jitted shard_map closure for hamming_topk_sharded, cached per
     (mesh, axis, l, use_kernel, select, pack) so steady serving traffic
     doesn't rebuild and re-trace the distributed scan on every call."""
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         partial(_local_then_merge, l=l, axis=axis, use_kernel=use_kernel,
                 select=select, pack=pack),
         mesh=mesh,
         in_specs=(P(axis, None), P()),
         out_specs=(P(), P()),
+        check_vma=False,
     ))
 
 
@@ -450,7 +442,7 @@ def hamming_topk_grouped_sharded(codes, queries, l: int, mesh,
     padding can never crowd a real global-top-l row out of its local list.
     """
     if use_kernel is None:
-        use_kernel = env_use_kernels(True)
+        use_kernel = env_use_kernels()
     select = env_fused_select(select)
     pack = env_cand_pack(pack)
     g, n, w = codes.shape
@@ -474,13 +466,14 @@ def _grouped_sharded_fn(mesh, axis: str, l: int, l_local: int, n_valid: int,
     the serving scan hot path doesn't rebuild and re-trace the distributed
     scan on every micro-batch (n_valid changes per index mutation, so churn
     rotates cache entries; the LRU bound keeps that in check)."""
-    return jax.jit(shard_map_compat(
+    return jax.jit(jax.shard_map(
         partial(_grouped_local_then_merge, l=l, l_local=l_local,
                 n_valid=n_valid, axis=axis, use_kernel=use_kernel,
                 select=select, pack=pack),
         mesh=mesh,
         in_specs=(P(None, axis, None), P()),
         out_specs=(P(), P()),
+        check_vma=False,
     ))
 
 

@@ -24,7 +24,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.functions import seeded_gaussian
-from repro.kernels.pallas_compat import CompilerParams
 
 WORD = 32
 
@@ -72,18 +71,36 @@ def bilinear_hash_kernel(x, u, v, *, block_n: int = 256, block_k: int = 128,
             pltpu.VMEM((block_n, block_k), jnp.float32),
             pltpu.VMEM((block_n, block_k), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, u, v)
 
 
 def _pack_sign_bits(prod):
-    bits = (prod >= 0).astype(jnp.uint32)          # sgn(0) = +1
-    bn, bk = bits.shape
-    bits = bits.reshape(bn, bk // WORD, WORD)
-    weights = jnp.uint32(1) << jnp.arange(WORD, dtype=jnp.uint32)
-    return (bits * weights).sum(axis=-1, dtype=jnp.uint32)
+    """Sign bits of a (BN, BK) tile packed 32 to a word: (BN, BK/32) uint32.
+
+    Two MXU products with power-of-two weights build each word's low and
+    high 16 bits.  Every operand (a 0/1 bit, a weight 2^i) is exact in
+    bf16 and every sum is below 2^16, so the packing is exact on every
+    backend; the halves are joined in int32 and bitcast, since Mosaic has
+    no unsigned lane reduction."""
+    bits = (prod >= 0).astype(jnp.bfloat16)        # sgn(0) = +1
+    bk = bits.shape[1]
+    shape = (bk, bk // WORD)
+    j = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    word = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    bit = j % WORD
+    mine = j // WORD == word
+
+    def half(lo_bit):
+        weight = jnp.where(mine & (bit >= lo_bit) & (bit < lo_bit + 16),
+                           jnp.int32(1) << (bit - lo_bit), 0)
+        return jnp.dot(bits, weight.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    packed = half(0) | (half(16) << 16)
+    return jax.lax.bitcast_convert_type(packed, jnp.uint32)
 
 
 def _seeded_kernel(seed_ref, x_ref, out_ref, acc_u, acc_v, *,
@@ -153,7 +170,7 @@ def bilinear_hash_seeded_kernel(x, seeds, *, k: int, block_n: int = 256,
             pltpu.VMEM((block_n, block_k), jnp.float32),
             pltpu.VMEM((block_n, block_k), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

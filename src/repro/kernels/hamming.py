@@ -16,14 +16,15 @@ Two families of kernels live here:
   matrix costs 2·n·B·4 = 256 bytes/point of HBM round-trip against a
   16-byte/point code table — the scan stops being bandwidth-bound on codes.
 - ``hamming_topk_fused_kernel`` — fuse selection into the scan.  Each grid
-  block popcounts its (block_n, W) tile against its B queries into VMEM
-  scratch and selects the block-local smallest-l candidates there
-  (deterministic ties: lowest row index wins); only (grid, B, l) candidate
-  (distance, row-id) pairs ever reach HBM.  A tiny second-stage merge over
-  grid·l ≪ n rows (see kernels/ops.py) yields the final (B, l) answer,
-  bit-identical to lax.top_k over the full distance matrix.
+  block popcounts its (block_n, W) tile against its B queries into a
+  (B, block_n) VMEM tile (rows on lanes) and selects the block-local
+  smallest-l candidates there (deterministic ties: lowest row index wins);
+  only (grid, B, l) candidate (distance, row-id) pairs ever reach HBM.  A
+  tiny second-stage merge over grid·l ≪ n rows (see kernels/ops.py) yields
+  the final (B, l) answer, bit-identical to lax.top_k over the full
+  distance matrix.
 - ``hamming_topk_hist_kernel`` — same contract, cheaper selection.  The
-  argmin kernel pays l rounds of masked argmin over the (block_n, B) tile:
+  argmin kernel pays l rounds of masked argmin over the (B, block_n) tile:
   O(l·block_n·B) VPU work that dominates once HBM traffic is minimized.
   Hamming distances over k-bit codes are bounded integers in [0, 32·W],
   exactly the counting-sort regime: a two-pass **distance-histogram
@@ -31,10 +32,12 @@ Two families of kernels live here:
   distance whose histogram prefix sum (CDF) reaches l — then emits every
   row with dist < r_b plus the lowest-row-index ties at r_b.  The CDF is
   evaluated lazily by bisection over the ≤ 32·W+1 possible distance
-  values (count(dist ≤ mid) is one compare-reduce pass), so selection
-  costs O(block_n·B·log(32W) + l·B·log(block_n)) instead of
+  values (count(dist ≤ mid) is one compare-reduce pass); row ranks are
+  exact MXU products over 128-row chunks, and each output slot locates
+  its row within one chunk, so selection costs
+  O(block_n·B·log(32W) + l·B·(block_n/128 + 128)) instead of
   O(l·block_n·B) — independent of l for the tile passes, which makes deep
-  scans (l in the hundreds) as cheap as shallow ones.  A ``dma=True``
+  scans (l in the hundreds) cheap.  A ``dma=True``
   variant additionally streams code tiles HBM→VMEM through a manually
   double-buffered ``pltpu.make_async_copy`` pipeline over the (G, blocks)
   grid, so popcount of tile i overlaps the fetch of tile i+1 (on CPU
@@ -55,7 +58,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 # Sentinel distance for masked (padded / out-of-range) rows: far above any
 # real Hamming distance (<= 32·W) but negatable in int32.
@@ -78,6 +80,10 @@ _CAND_ID_MAX = 0x7FFF                  # ids are int16 in both narrow packs
 # abstractly evaluates each registered entrypoint's launch geometry against
 # it — keep the two in sync.
 VMEM_BUDGET_BYTES = 16 * 2**20
+
+# Lane width of a vreg.  The fused selects keep rows on lanes, so block_n
+# must be a multiple of it (ops._block_rows rounds to it).
+LANE = 128
 
 
 def cand_encoding(pack: str, w: int, block_n: int):
@@ -135,7 +141,7 @@ def hamming_distance_kernel(codes, query, *, block_n: int = 2048,
         ],
         out_specs=pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(codes, query[None, :])
@@ -153,17 +159,19 @@ def _topk_fused_kernel(*refs, n_words: int, l: int, block_n: int,
     """One grid step: scan a (block_n, W) code tile against this group's B
     queries and emit the block-local smallest-l (distance, row-id) pairs.
 
-    The (block_n, B) distance tile lives only in VMEM scratch (``acc_ref``)
+    The (B, block_n) distance tile lives only in VMEM scratch (``acc_ref``)
     — it is never written to HBM.  Selection is l rounds of masked argmin;
     ``jnp.min`` over the row-iota of the minima keeps ties deterministic
-    (lowest row index wins), matching lax.top_k's stable order.
+    (lowest row index wins), matching lax.top_k's stable order.  Each
+    round's (B, 1) minima land in column j of a (B, l) register tile, and
+    the tile is stored once after the last round.
 
     Emitted ids are BLOCK-LOCAL (< block_n) and distances are clamped to
     the pack's sentinel, so both fit the narrow candidate dtype; the merge
     in ops.py widens and adds the block base back.  Selection still runs on
     the full int32 tile — only the HBM emission narrows.
 
-    masked=True threads an extra (block_n, 1) int32 activity tile: rows
+    masked=True threads an extra (1, block_n) int32 activity row: rows
     whose flag is 0 (tombstones / pad) go to the sentinel before selection,
     exactly like rows past n_valid.
     """
@@ -172,34 +180,35 @@ def _topk_fused_kernel(*refs, n_words: int, l: int, block_n: int,
          out_d_ref, out_i_ref, acc_ref) = refs
     else:
         codes_ref, queries_ref, out_d_ref, out_i_ref, acc_ref = refs
-    d_dtype, i_dtype, d_sent = cand_encoding(pack, n_words, block_n)
-    # (block_n, W) codes vs this group's (B, W) queries, word-by-word XOR
-    # on 2-D (BN, B) lanes — the natural VPU layout.
-    acc = _popcount_tile(codes_ref[0], queries_ref[0], n_words)
+    acc = _popcount_rows(codes_ref[0].T, queries_ref[0], n_words)
     # group-local row ids for this block; rows past the group's live region
     # (block padding) are masked to the sentinel so they always rank last.
-    block_in_group = pl.program_id(1)
-    base = block_in_group * block_n
-    rows = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+    base = pl.program_id(1) * block_n
+    rows = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
     acc = jnp.where(base + rows >= n_valid, jnp.int32(DIST_SENTINEL), acc)
     if masked:
         acc = jnp.where(act_ref[...] > 0, acc, jnp.int32(DIST_SENTINEL))
     acc_ref[...] = acc
     big_row = jnp.int32(jnp.iinfo(jnp.int32).max)
+    b = acc.shape[0]
+    slots = jax.lax.broadcasted_iota(jnp.int32, (b, l), 1)
 
-    def select_one(j, _):
+    def select_one(j, tiles):
+        out_d, out_i = tiles
         acc = acc_ref[...]
-        dmin = jnp.min(acc, axis=0)                               # (B,)
-        hit = acc == dmin[None, :]
-        rmin = jnp.min(jnp.where(hit, rows, big_row), axis=0)     # (B,)
-        out_d_ref[0, 0, :, pl.dslice(j, 1)] = \
-            jnp.minimum(dmin, d_sent)[:, None].astype(d_dtype)
-        out_i_ref[0, 0, :, pl.dslice(j, 1)] = rmin[:, None].astype(i_dtype)
-        acc_ref[...] = jnp.where(rows == rmin[None, :],
-                                 jnp.int32(DIST_SENTINEL), acc)
-        return _
+        dmin = jnp.min(acc, axis=1, keepdims=True)                # (B, 1)
+        hit = acc == dmin
+        rmin = jnp.min(jnp.where(hit, rows, big_row), axis=1,
+                       keepdims=True)                             # (B, 1)
+        acc_ref[...] = jnp.where(rows == rmin, jnp.int32(DIST_SENTINEL),
+                                 acc)
+        return (jnp.where(slots == j, dmin, out_d),
+                jnp.where(slots == j, rmin, out_i))
 
-    jax.lax.fori_loop(0, l, select_one, 0)
+    zero = jnp.zeros((b, l), jnp.int32)
+    out_d, out_i = jax.lax.fori_loop(0, l, select_one, (zero, zero))
+    out_d_ref[0, 0], out_i_ref[0, 0] = _pack_cand(out_d, out_i, pack,
+                                                  n_words, block_n)
 
 
 @functools.partial(jax.jit, static_argnames=("l", "n_valid", "block_n",
@@ -221,7 +230,7 @@ def hamming_topk_fused_kernel(codes, queries, l: int, n_valid: int, *,
     Selection always runs on the int32 VMEM tile; only the HBM-bound
     emission narrows, so results are bit-identical after widening.
 
-    active: optional (n_pad, 1) int32 per-row activity flags, shared by all
+    active: optional (1, n_pad) int32 per-row activity flags, shared by all
     G groups; rows with flag 0 are masked to the sentinel before selection.
     A TRACED operand (its value is not a jit key), so mutable-index serving
     can flip tombstones without recompiling the scan.
@@ -238,7 +247,7 @@ def hamming_topk_fused_kernel(codes, queries, l: int, n_valid: int, *,
     ]
     operands = [codes, queries]
     if active is not None:
-        in_specs.append(pl.BlockSpec((block_n, 1), lambda t, i: (i, 0)))
+        in_specs.append(pl.BlockSpec((1, block_n), lambda t, i: (0, i)))
         operands.append(active)
     return pl.pallas_call(
         functools.partial(_topk_fused_kernel, n_words=w, l=l,
@@ -251,8 +260,8 @@ def hamming_topk_fused_kernel(codes, queries, l: int, n_valid: int, *,
             pl.BlockSpec((1, 1, b, l), lambda t, i: (t, i, 0, 0)),
         ],
         out_shape=out_shapes,
-        scratch_shapes=[pltpu.VMEM((block_n, b), jnp.int32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.VMEM((b, block_n), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -268,9 +277,57 @@ def _popcount_tile(codes, queries, n_words: int):
     return acc
 
 
+def _popcount_rows(codes_t, queries, n_words: int):
+    """(W, block_n) codes vs (B, W) queries -> (B, block_n) int32 distances
+    with the rows on lanes: the fused selects reduce over rows, and a
+    (B, block_n) tile fills every lane where a (block_n, B) one would use
+    B of 128."""
+    acc = jnp.zeros((queries.shape[0], codes_t.shape[1]), jnp.int32)
+    for w in range(n_words):
+        acc += _popcount_u32(jnp.bitwise_xor(codes_t[w:w + 1, :],
+                                             queries[:, w:w + 1]))
+    return acc
+
+
+def _dot_exact(a, b):
+    """Product of small non-negative integer tiles on the MXU, exact on
+    every backend: bf16 holds each operand (0/1 or <= 256) exactly and the
+    f32 accumulator holds every sum below 2**24."""
+    return jnp.dot(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def _gather_exact(onehot, vals, vmax: int):
+    """One-hot gather ``onehot @ vals`` of integers in [0, vmax], split
+    into 8-bit digits so each MXU pass stays exact."""
+    out = _dot_exact(onehot, vals & 0xFF)
+    for shift in range(8, max(8, vmax.bit_length()), 8):
+        out += _dot_exact(onehot, (vals >> shift) & 0xFF) << shift
+    return out
+
+
+def _chunk_prefix(x):
+    """Inclusive prefix counts of a 0/1 (B, block_n) tile along its rows,
+    by 128-row chunks.  Returns (within (B, nc, 128): the count inside the
+    chunk up to and including each row, start (B, nc): the count in all
+    earlier chunks).  The in-chunk scan is one MXU product with a
+    triangular 0/1 matrix; the chunk offsets are a small VPU reduction."""
+    b, bn = x.shape
+    nc = bn // LANE
+    r = jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 1)
+    within = _dot_exact(x.reshape(b * nc, LANE),
+                        (r <= c).astype(jnp.int32)).reshape(b, nc, LANE)
+    tot = within[:, :, LANE - 1]                              # (B, nc)
+    earlier = (jax.lax.broadcasted_iota(jnp.int32, (1, nc, nc), 2)
+               < jax.lax.broadcasted_iota(jnp.int32, (1, nc, nc), 1))
+    start = jnp.sum(jnp.where(earlier, tot[:, None, :], 0), axis=2)
+    return within, start
+
+
 def _hist_select(acc, base, l: int, n_valid: int, max_dist: int,
                  block_n: int, act=None):
-    """Two-pass counting-sort select over one (block_n, B) distance tile.
+    """Two-pass counting-sort select over one (B, block_n) distance tile.
 
     Pass 1 finds, per query, the cutoff radius r_b = the smallest distance
     value whose histogram prefix sum reaches t = min(l, live rows in this
@@ -278,68 +335,101 @@ def _hist_select(acc, base, l: int, n_valid: int, max_dist: int,
     bisection over [0, max_dist] — each probe is one compare-reduce pass —
     instead of materializing all ≤ max_dist+1 bins: O(block_n·B·log maxd).
 
-    Pass 2 emits the rows with dist < r_b plus the deterministically-tied
-    rows at r_b (lowest row index wins, matching lax.top_k's stable order):
-    a cumsum over the keep mask assigns each kept row its output slot, and
-    a per-slot bisection over that cumsum (lower bound of slot j+1) turns
-    the scatter into l·B small gathers: O(l·B·log block_n).  Output slots
-    are in row order, NOT distance order — the contract only requires the
-    exact smallest-l *set* per block (ties to lowest row); the second-stage
+    Pass 2 keeps the rows with dist < r_b plus the deterministically-tied
+    rows at r_b (lowest row index wins, matching lax.top_k's stable order;
+    the tie rank is a row prefix count) and emits kept row j to output
+    slot j.  Rows are cut into 128-row chunks: ``_chunk_prefix`` gives each
+    row its rank, slot j finds its chunk by comparing j with the (B, nc)
+    chunk offsets, and one MXU product with that one-hot gathers the
+    chunk's ranks and distances, whose lane count locates the row:
+    O(l·B·(nc + 128)) instead of O(l·B·block_n).  Output slots are in row
+    order, NOT distance order — the contract only requires the exact
+    smallest-l *set* per block (ties to lowest row); the second-stage
     lexicographic (distance, id) merge in ops.py restores sorted order.
 
-    Returns (out_d, out_i): (B, l) int32 with BLOCK-LOCAL ids (< block_n;
-    the merge adds the block base back); slots past the live-row count
-    carry (DIST_SENTINEL, garbage local id) exactly like the exhausted
-    slots of the argmin kernel — the merge maps them to id -1.
+    acc: (B, block_n) int32 distances; act: optional (1, block_n) int32
+    activity row.  Returns (out_d, out_i): (B, l) int32 with BLOCK-LOCAL
+    ids (< block_n; the merge adds the block base back); slots past the
+    live-row count carry (DIST_SENTINEL, block_n - 1) exactly like the
+    exhausted slots of the argmin kernel — the merge maps them to id -1.
     """
-    rows = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
-    acc = jnp.where(base + rows >= n_valid, jnp.int32(DIST_SENTINEL), acc)
-    b = acc.shape[1]
-    # live rows in this block; also the per-query selection target t <= l.
+    b, bn = acc.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
+    live = base + rows < n_valid                              # (1, block_n)
     if act is None:
         t = jnp.minimum(jnp.clip(n_valid - base, 0, block_n), l)  # scalar
     else:
         # activity flags (tombstones / pad) shrink the live count further;
         # traced, so flipping a tombstone never recompiles the select
-        ri = jax.lax.broadcasted_iota(jnp.int32, act.shape, 0)
-        live = (act > 0) & (base + ri < n_valid)          # (block_n, 1)
-        acc = jnp.where(live, acc, jnp.int32(DIST_SENTINEL))
+        live = live & (act > 0)
         t = jnp.minimum(jnp.sum(live.astype(jnp.int32)), l)
+    acc = jnp.where(live, acc, jnp.int32(DIST_SENTINEL))
 
     # -- pass 1: cutoff radius per query via bisection on the distance CDF.
     # invariant: count(acc <= hi) >= t (true at hi = max_dist: every live
     # row's distance is <= 32·W and padding rows sit at the sentinel).
-    lo = jnp.zeros((1, b), jnp.int32)
-    hi = jnp.full((1, b), max_dist, jnp.int32)
+    lo = jnp.zeros((b, 1), jnp.int32)
+    hi = jnp.full((b, 1), max_dist, jnp.int32)
     for _ in range(max(1, max_dist.bit_length())):
         mid = (lo + hi) >> 1
-        cnt = jnp.sum((acc <= mid).astype(jnp.int32), axis=0, keepdims=True)
+        cnt = jnp.sum((acc <= mid).astype(jnp.int32), axis=1, keepdims=True)
         ge = cnt >= t
         hi = jnp.where(ge, mid, hi)
         lo = jnp.where(ge, lo, mid + 1)
-    r = hi                                                    # (1, B)
+    r = hi                                                    # (B, 1)
 
     # -- pass 2: keep mask with lowest-row-index ties at the cutoff.
-    less = jnp.sum((acc < r).astype(jnp.int32), axis=0, keepdims=True)
+    less = jnp.sum((acc < r).astype(jnp.int32), axis=1, keepdims=True)
     tie = acc == r
-    tie_rank = jnp.cumsum(tie.astype(jnp.int32), axis=0) - 1
+    within, start = _chunk_prefix(tie.astype(jnp.int32))
+    tie_rank = (within + start[:, :, None]).reshape(b, bn) - 1
     keep = (acc < r) | (tie & (tie_rank < (t - less)))
-    pos = jnp.cumsum(keep.astype(jnp.int32), axis=0)          # 1-based slots
-    # emit: lower-bound bisection over the monotone cumsum finds, for every
-    # output slot j, the row holding the (j+1)-th kept candidate.
-    tj = jax.lax.broadcasted_iota(jnp.int32, (l, b), 0) + 1   # targets
-    lo2 = jnp.zeros((l, b), jnp.int32)
-    hi2 = jnp.full((l, b), block_n - 1, jnp.int32)
-    for _ in range(max(1, (block_n - 1).bit_length())):
-        mid = (lo2 + hi2) >> 1
-        cm = jnp.take_along_axis(pos, mid, axis=0)            # (l, B)
-        ge = cm >= tj
-        hi2 = jnp.where(ge, mid, hi2)
-        lo2 = jnp.where(ge, lo2, mid + 1)
-    d_sel = jnp.take_along_axis(acc, hi2, axis=0)             # (l, B)
-    slot_ok = tj <= t
+
+    # -- emit: kept row j goes to slot j, located chunk by chunk, one query
+    # at a time so every temporary is a small (l, 128) tile.
+    within, start = _chunk_prefix(keep.astype(jnp.int32))
+    end = start + within[:, :, LANE - 1]                      # (B, nc)
+    nc = bn // LANE
+    # bound the distances so the one-hot gather stays exact: every kept
+    # row sits at <= max_dist, the rest never reach an output slot.
+    acc_c = jnp.minimum(acc, max_dist + 1).reshape(b, nc, LANE)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (l, 1), 0)
+    chunk_id = jax.lax.broadcasted_iota(jnp.int32, (1, nc), 1)
+    lane_id = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
+    query = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
+
+    def emit(within_ref, acc_ref, start_ref, end_ref):
+        within_ref[...] = within
+        acc_ref[...] = acc_c
+        start_ref[...] = start[:, None, :]
+        end_ref[...] = end[:, None, :]
+
+        def emit_one(q, tiles):
+            start_q, end_q = start_ref[q], end_ref[q]          # (1, nc)
+            onehot = ((start_q <= slot) & (slot < end_q)).astype(jnp.int32)
+            off = jnp.sum(onehot * start_q, axis=1, keepdims=True)  # (l, 1)
+            chunk = jnp.sum(onehot * chunk_id, axis=1, keepdims=True)
+            rank = _dot_exact(onehot, within_ref[q])           # (l, 128)
+            lane = jnp.sum((rank <= slot - off).astype(jnp.int32), axis=1,
+                           keepdims=True)                      # (l, 1)
+            dist = _gather_exact(onehot, acc_ref[q], max_dist + 1)
+            d_sel = jnp.sum(jnp.where(lane_id == lane, dist, 0), axis=1,
+                            keepdims=True)
+            mine = query == q
+            return (jnp.where(mine, d_sel, tiles[0]),
+                    jnp.where(mine, chunk * LANE + lane, tiles[1]))
+
+        zero = jnp.zeros((l, b), jnp.int32)
+        return jax.lax.fori_loop(0, b, emit_one, (zero, zero))
+
+    d_sel, i_sel = pl.run_scoped(
+        emit, pltpu.VMEM((b, nc, LANE), jnp.int32),
+        pltpu.VMEM((b, nc, LANE), jnp.int32),
+        pltpu.VMEM((b, 1, nc), jnp.int32), pltpu.VMEM((b, 1, nc), jnp.int32))
+    slot_ok = slot < t                                        # (l, 1)
     out_d = jnp.where(slot_ok, d_sel, jnp.int32(DIST_SENTINEL))
-    return out_d.T, hi2.T                                     # (B, l) each
+    out_i = jnp.where(slot_ok, i_sel, bn - 1)
+    return out_d.T, out_i.T                                   # (B, l) each
 
 
 def _pack_cand(out_d, out_i, pack: str, n_words: int, block_n: int):
@@ -356,7 +446,7 @@ def _topk_hist_kernel(*refs, n_words: int, l: int, block_n: int,
                       masked: bool = False):
     """One grid step of the histogram-select fused scan (BlockSpec-streamed
     code tiles; see _topk_hist_dma_kernel for the manual-DMA variant).
-    masked=True threads a (block_n, 1) int32 activity tile into the select
+    masked=True threads a (1, block_n) int32 activity row into the select
     (rows with flag 0 rank at the sentinel)."""
     if masked:
         codes_ref, queries_ref, act_ref, out_d_ref, out_i_ref = refs
@@ -364,7 +454,7 @@ def _topk_hist_kernel(*refs, n_words: int, l: int, block_n: int,
     else:
         codes_ref, queries_ref, out_d_ref, out_i_ref = refs
         act = None
-    acc = _popcount_tile(codes_ref[0], queries_ref[0], n_words)
+    acc = _popcount_rows(codes_ref[0].T, queries_ref[0], n_words)
     base = pl.program_id(1) * block_n
     out_d, out_i = _hist_select(acc, base, l, n_valid, max_dist, block_n,
                                 act)
@@ -378,7 +468,10 @@ def _topk_hist_dma_kernel(*refs, n_words: int, l: int,
                           masked: bool = False):
     """Histogram-select step with a double-buffered HBM→VMEM code pipeline.
 
-    The code stack stays in HBM (memory_space=ANY); each sequential step of
+    The code stack stays in HBM (memory_space=ANY), transposed to
+    (G, W, n_pad) so that a tile's copy slices only the lane-dense row
+    axis (Mosaic refuses a DMA whose last dimension is a sub-128 W); each
+    sequential step of
     the (G, blocks) grid waits on the async copy of its own tile (started
     by the previous step) and immediately starts the copy of the next tile
     into the other buffer, so the popcount of tile i overlaps the fetch of
@@ -404,7 +497,7 @@ def _topk_hist_dma_kernel(*refs, n_words: int, l: int,
 
     def copy_tile(slot_idx, g_idx, blk_idx):
         return pltpu.make_async_copy(
-            codes_hbm_ref.at[g_idx, pl.dslice(blk_idx * block_n, block_n), :],
+            codes_hbm_ref.at[g_idx, :, pl.dslice(blk_idx * block_n, block_n)],
             buf_ref.at[slot_idx],
             sem_ref.at[slot_idx])
 
@@ -417,7 +510,7 @@ def _topk_hist_dma_kernel(*refs, n_words: int, l: int,
         copy_tile(nxt_slot, nxt_t, nxt_i).start()
 
     copy_tile(slot, t, i).wait()
-    acc = _popcount_tile(buf_ref[slot], queries_ref[0], n_words)
+    acc = _popcount_rows(buf_ref[slot], queries_ref[0], n_words)
     out_d, out_i = _hist_select(acc, i * block_n, l, n_valid, max_dist,
                                 block_n, act)
     out_d_ref[0, 0], out_i_ref[0, 0] = _pack_cand(out_d, out_i, pack,
@@ -444,10 +537,11 @@ def hamming_topk_hist_kernel(codes, queries, l: int, n_valid: int, *,
     runs on the int32 VMEM tile.
 
     dma=True streams code tiles through the manually double-buffered async
-    copy pipeline (the kernel then reads ``codes`` from HBM/ANY memory
-    space); dma=False uses ordinary BlockSpec streaming.  Both are exact.
+    copy pipeline (the kernel then reads a (G, W, n_pad) transpose of
+    ``codes`` from HBM/ANY memory space); dma=False uses ordinary BlockSpec
+    streaming.  Both are exact.
 
-    active: optional (n_pad, 1) int32 per-row activity flags shared by all
+    active: optional (1, n_pad) int32 per-row activity flags shared by all
     G groups (0 = tombstone / pad -> sentinel before selection); traced, so
     serving can flip tombstones without recompiling.
     """
@@ -462,7 +556,7 @@ def hamming_topk_hist_kernel(codes, queries, l: int, n_valid: int, *,
         pl.BlockSpec((1, 1, b, l), lambda t, i: (t, i, 0, 0)),
         pl.BlockSpec((1, 1, b, l), lambda t, i: (t, i, 0, 0)),
     ]
-    act_spec = pl.BlockSpec((block_n, 1), lambda t, i: (i, 0))
+    act_spec = pl.BlockSpec((1, block_n), lambda t, i: (0, i))
     if not dma:
         in_specs = [
             pl.BlockSpec((1, block_n, w), lambda t, i: (t, i, 0)),
@@ -481,15 +575,15 @@ def hamming_topk_hist_kernel(codes, queries, l: int, n_valid: int, *,
             in_specs=in_specs,
             out_specs=out_specs,
             out_shape=out_shapes,
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
         )(*operands)
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),         # codes stay in HBM
+        pl.BlockSpec(memory_space=pl.ANY),            # codes stay in HBM
         pl.BlockSpec((1, b, w), lambda t, i: (t, 0, 0)),
     ]
-    operands = [codes, queries]
+    operands = [jnp.swapaxes(codes, 1, 2), queries]
     if active is not None:
         in_specs.append(act_spec)
         operands.append(active)
@@ -503,10 +597,10 @@ def hamming_topk_hist_kernel(codes, queries, l: int, n_valid: int, *,
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=[
-            pltpu.VMEM((2, block_n, w), jnp.uint32),  # double buffer
+            pltpu.VMEM((2, w, block_n), jnp.uint32),  # double buffer
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -530,7 +624,7 @@ def hamming_distance_batch_kernel(codes, queries, *, block_n: int = 2048,
         ],
         out_specs=pl.BlockSpec((block_n, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, b), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(codes, queries)

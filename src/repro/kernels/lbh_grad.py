@@ -19,25 +19,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
-
-def _kernel(p_ref, q_ref, r_ref, sq_ref, sp_ref):
-    p = p_ref[...]          # (1, m)
-    q = q_ref[...]          # (1, m)
-    b = jnp.tanh(0.5 * p * q)                       # (1, m)
-    rows = r_ref[...]                               # (BM, m)
-    # (R b) for this tile of rows: contract m against b.
-    rb = jnp.dot(rows, b[0, :], preferred_element_type=jnp.float32)  # (BM,)
-    i = pl.program_id(0)
-    bm = rows.shape[0]
-    b_tile = jax.lax.dynamic_slice_in_dim(b[0], i * bm, bm)
-    q_tile = jax.lax.dynamic_slice_in_dim(q[0], i * bm, bm)
-    p_tile = jax.lax.dynamic_slice_in_dim(p[0], i * bm, bm)
-    s = rb * (1.0 - b_tile * b_tile)                # (BM,)
-    sq_ref[...] = (s * q_tile)[None, :]
-    sp_ref[...] = (s * p_tile)[None, :]
+def _kernel(p_ref, q_ref, pt_ref, qt_ref, r_ref, sq_ref, sp_ref):
+    b = jnp.tanh(0.5 * p_ref[...] * q_ref[...])        # (1, m)
+    # (R b) for this tile of rows, as a (1, BM) row: contract m.
+    rb = jax.lax.dot_general(b, r_ref[...], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    p_tile, q_tile = pt_ref[...], qt_ref[...]          # (1, BM)
+    b_tile = jnp.tanh(0.5 * p_tile * q_tile)
+    s = rb * (1.0 - b_tile * b_tile)
+    sq_ref[...] = s * q_tile
+    sp_ref[...] = s * p_tile
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
@@ -52,6 +46,8 @@ def lbh_chain_kernel(p, q, r, *, block_m: int = 512, interpret: bool = False):
         in_specs=[
             pl.BlockSpec((1, m), lambda i: (0, 0)),
             pl.BlockSpec((1, m), lambda i: (0, 0)),
+            pl.BlockSpec((1, block_m), lambda i: (0, i)),
+            pl.BlockSpec((1, block_m), lambda i: (0, i)),
             pl.BlockSpec((block_m, m), lambda i: (i, 0)),
         ],
         out_specs=[
@@ -62,8 +58,8 @@ def lbh_chain_kernel(p, q, r, *, block_m: int = 512, interpret: bool = False):
             jax.ShapeDtypeStruct((1, m), jnp.float32),
             jax.ShapeDtypeStruct((1, m), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(p[None, :], q[None, :], r)
+    )(p[None, :], q[None, :], p[None, :], q[None, :], r)
     return sq[0], sp[0]

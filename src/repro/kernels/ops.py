@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from repro.core.search import env_cand_pack, env_fused_select
 from repro.kernels.bilinear_hash import (bilinear_hash_kernel,
                                          bilinear_hash_seeded_kernel)
-from repro.kernels.hamming import (DIST_SENTINEL, cand_encoding,
+from repro.kernels.hamming import (DIST_SENTINEL, LANE, cand_encoding,
                                    hamming_distance_batch_kernel,
                                    hamming_distance_kernel,
                                    hamming_topk_fused_kernel,
@@ -23,7 +23,7 @@ from repro.kernels.lbh_grad import lbh_chain_kernel
 from repro.utils.bits import n_words
 
 WORD = 32
-SUBLANE = 8   # f32/i32 sublane quantum: row-block sizes must be multiples
+SUBLANE = 8   # f32/i32 sublane quantum: the query batch is padded to it
 
 
 def _interpret_default(interpret):
@@ -32,12 +32,20 @@ def _interpret_default(interpret):
     return interpret
 
 
-def _block_rows(n: int, block_n: int) -> int:
-    """Row-block size for an n-row scan: at most block_n, at least
-    min(n, 256), rounded UP to the sublane quantum (a raw min(block_n, n)
-    could pick e.g. 300, which is not a legal (8, 128)-tiled block)."""
-    bn = min(block_n, max(256, n))
-    return -(-bn // SUBLANE) * SUBLANE
+# Elements of one (B, block_n) int32 distance tile.  The fused selects keep
+# a few such tiles live in VMEM; past this size a large query batch would
+# overflow a v5e core's 16 MiB scoped VMEM, so the row block shrinks.
+_TILE_ELEMS = 2 ** 18
+
+
+def _block_rows(n: int, block_n: int, b: int = 1) -> int:
+    """Row-block size for an n-row scan of a b-query batch: at most
+    block_n (and at most _TILE_ELEMS / b), at least min(n, 256), rounded UP
+    to the lane width (a raw min(block_n, n) could pick e.g. 300, which is
+    not a legal (8, 128)-tiled block, and the fused selects lay a block's
+    rows along 128-wide lanes)."""
+    bn = min(block_n, max(256, n), max(256, _TILE_ELEMS // b))
+    return -(-bn // LANE) * LANE
 
 
 def _pad_to(x, axis: int, mult: int):
@@ -240,13 +248,13 @@ def _topk_grouped_impl(codes, queries, active, l: int, *, block_n: int,
                        interpret: bool, select: str, dma: bool, pack: str):
     g, n, w = codes.shape
     b = queries.shape[1]
-    bn = _block_rows(n, block_n)
-    padded = _pad_to(codes, 1, bn)
     q = _pad_to(queries, 1, SUBLANE)
+    bn = _block_rows(n, block_n, q.shape[1])
+    padded = _pad_to(codes, 1, bn)
     l_k = min(l, bn)    # a block holds bn rows; l_k = bn already emits all
     act = None
     if active is not None:
-        act = _pad_to(active.astype(jnp.int32)[:, None], 0, bn)
+        act = _pad_to(active.astype(jnp.int32)[None, :], 1, bn)
     if select == "hist":
         cd, ci = hamming_topk_hist_kernel(
             padded, q, l_k, n, active=act, block_n=bn, interpret=interpret,
@@ -298,7 +306,7 @@ def scan_cand_model(n: int, b: int, l: int, block_n: int = 4096,
     check_regression.py gates — at B=32, l=128 it rivals the code stream
     itself, so halving it is the difference between a scan that is
     code-stream-bound and one that is not."""
-    bn = _block_rows(n, block_n)
+    bn = _block_rows(n, block_n, -(-b // SUBLANE) * SUBLANE)
     grid = -(-n // bn)
     return 2 * g * grid * b * min(l, bn) * CAND_PAIR_BYTES[pack]
 
@@ -367,19 +375,20 @@ def scan_select_model(n: int, b: int, l: int = 16, k: int = 128,
       sentinel mask update) -> 3·l·block_n·B per block.  Grows linearly
       with l — at l=512 the selection costs 1536 tile passes.
     - ``hist``: two-pass counting-sort select; the distance-CDF bisection
-      is ceil(log2(32·ceil(k/32)+1)) compare-reduce tile passes, plus ~5
-      fixed passes (cutoff counts, tie cumsum, keep mask, slot cumsum) and
-      an emission bisection over the slot cumsum costing
-      2·ceil(log2(block_n))·l·B (small: l·B elements, not block_n·B) ->
-      independent of l in the tile term.
+      is ceil(log2(32·ceil(k/32)+1)) compare-reduce tile passes, plus ~6
+      fixed passes (cutoff counts, tie and keep masks, chunk-prefix
+      offsets); the row prefix counts are MXU products (not counted), and
+      the emission costs ~4·(block_n/128 + 128)·l·B per block (each slot
+      compares against the chunk offsets, then scans one 128-row chunk
+      for its rank and distance) -> flat in l in the tile term.
 
-    The crossover sits near l ≈ (log2(32W) + 5) / 3 ≈ 4; everywhere the
-    serving paths operate (l ≥ 8) the histogram select is cheaper, and at
-    l = 128 it models ~28x fewer element-ops.  Deterministic arithmetic —
+    The crossover sits near l ≈ 5; everywhere the serving paths operate
+    (l ≥ 8) the histogram select is cheaper, and at l = 128, B = 32 it
+    models ~11x fewer element-ops.  Deterministic arithmetic —
     benchmarks/check_regression.py gates on the modeled ratio, which
     cannot flake.
     """
-    bn = _block_rows(n, block_n)
+    bn = _block_rows(n, block_n, -(-b // SUBLANE) * SUBLANE)
     grid = -(-n // bn)
     l_k = min(l, bn)
     w = n_words(k)
@@ -387,8 +396,8 @@ def scan_select_model(n: int, b: int, l: int = 16, k: int = 128,
         per_block = 3 * l_k * bn * b
     else:
         cdf_steps = max(1, (32 * w).bit_length())
-        emit_steps = max(1, (bn - 1).bit_length())
-        per_block = (cdf_steps + 5) * bn * b + 2 * emit_steps * l_k * b
+        per_block = ((cdf_steps + 6) * bn * b
+                     + 4 * (bn // LANE + LANE) * l_k * b)
     return g * grid * per_block
 
 

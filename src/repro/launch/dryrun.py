@@ -64,11 +64,6 @@ def count_params(cfg):
     return total, active
 
 
-def _set_mesh(mesh):
-    """jax.set_mesh (jax >= 0.5) or the Mesh context manager (jax 0.4.x)."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
 def _opt_shardings(mesh, rules, log_axes_tree, abs_params, opt_abs):
     """Moments mirror the param shardings exactly; int8-quantized moments
     are shape-preserving, so codes reuse the param sharding and the
@@ -174,7 +169,7 @@ def build_lowered(arch: str, shape_name: str, multi_pod: bool,
                      donate_argnums=(0, 1))
         act_spec = batch_spec(mesh, shape.global_batch, 3, seq_dim=1,
                               seq_len=shape.seq_len)
-        with _set_mesh(mesh), activation_sharding(act_spec), \
+        with jax.set_mesh(mesh), activation_sharding(act_spec), \
                 _moe_ctx(mesh, cfg, rules, shape.global_batch // nmb):
             lowered = fn.lower(abs_params, opt_abs, batch_abs)
         return lowered, mesh, cfg, shape
@@ -190,7 +185,7 @@ def build_lowered(arch: str, shape_name: str, multi_pod: bool,
         fn = jax.jit(fn0, in_shardings=(p_sh, in_sh), out_shardings=out_sh)
         act_spec = batch_spec(mesh, shape.global_batch, 3, seq_dim=1,
                               seq_len=shape.seq_len)
-        with _set_mesh(mesh), activation_sharding(act_spec), \
+        with jax.set_mesh(mesh), activation_sharding(act_spec), \
                 _moe_ctx(mesh, cfg, rules, shape.global_batch):
             lowered = fn.lower(abs_params, inputs)
         return lowered, mesh, cfg, shape
@@ -207,7 +202,7 @@ def build_lowered(arch: str, shape_name: str, multi_pod: bool,
     fn = jax.jit(fn0, in_shardings=(p_sh, c_sh, inp_sh, repl),
                  out_shardings=(out_tok_sh, c_sh), donate_argnums=(1,))
     act_spec = batch_spec(mesh, shape.global_batch, 3)
-    with _set_mesh(mesh), activation_sharding(act_spec), \
+    with jax.set_mesh(mesh), activation_sharding(act_spec), \
             _moe_ctx(mesh, cfg, rules, shape.global_batch):
         lowered = fn.lower(abs_params, cache_abs, inp_abs, pos_abs)
     return lowered, mesh, cfg, shape

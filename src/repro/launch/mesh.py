@@ -8,13 +8,14 @@ and tests/benches must keep seeing the single real device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi_pod adds the 2-pod DCN axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(devices: int | None = None, model: int = 2):
@@ -22,4 +23,5 @@ def make_debug_mesh(devices: int | None = None, model: int = 2):
     --xla_force_host_platform_device_count)."""
     n = devices or jax.device_count()
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -53,7 +53,7 @@ TRACED_OPERAND_NAMES = frozenset(
 
 # Wrappers whose first positional argument is the function that actually
 # gets traced — unwrapped when resolving jit(...) / pallas_call(...) roots.
-_UNWRAP = {"partial", "shard_map_compat", "shard_map", "vmap", "checkpoint",
+_UNWRAP = {"partial", "shard_map", "vmap", "checkpoint",
            "remat"}
 
 _CONFIG_NAMES = {"config", "cfg"}

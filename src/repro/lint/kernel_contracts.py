@@ -300,7 +300,7 @@ def default_registry() -> list:
                             args = [z((g, n_pad, w)), z((g, b, w)),
                                     min(l, block_n), n_pad - 3]
                             if masked:
-                                kw["active"] = z((n_pad, 1), jnp.int32)
+                                kw["active"] = z((1, n_pad), jnp.int32)
                             yield Case(
                                 f"bn{block_n}-w{w}-b{b}-l{l}-{pack}"
                                 f"{'-dma' if dma else ''}"

@@ -87,13 +87,17 @@ class ExhaustiveSelector:
         return self
 
     def select_all(self, w_all: jnp.ndarray, unlabeled: np.ndarray):
-        """(C,) argmin-margin indices over the unlabeled pool, per class."""
-        margins = jnp.abs(self.x @ w_all.T)      # (n, C); ||w|| drops in argmin
+        """(C,) argmin-margin indices over the unlabeled pool, per class.
+
+        The products run at full f32 precision: the TPU's default matmul
+        rounds operands to bf16, which can miss the true minimum."""
+        margins = jnp.abs(jnp.matmul(self.x, w_all.T, precision="highest"))
+        # (n, C); ||w|| drops in the argmin
         margins = jnp.where(jnp.asarray(unlabeled)[:, None], margins, jnp.inf)
         return np.asarray(jnp.argmin(margins, axis=0))
 
     def select(self, c: int, w, unlabeled: np.ndarray):
-        m = jnp.abs(self.x @ w)
+        m = jnp.abs(jnp.matmul(self.x, w, precision="highest"))
         m = jnp.where(jnp.asarray(unlabeled), m, jnp.inf)
         return int(jnp.argmin(m)), True
 
@@ -219,8 +223,10 @@ def run_active_learning(corpus: Corpus, selector, config: ALConfig) -> ALResult:
     nonempty = np.zeros(c_num, np.int64)
     select_s = 0.0
 
+    # x and labels are operands, not closed-over constants: a constant the
+    # size of the corpus would be embedded in the compiled program
     @jax.jit
-    def mean_ap(w_all, labeled_mask):
+    def mean_ap(w_all, labeled_mask, x, labels):
         unl = ~labeled_mask
         scores = x @ w_all.T                       # (n, C)
         def ap_c(c):
@@ -231,7 +237,8 @@ def run_active_learning(corpus: Corpus, selector, config: ALConfig) -> ALResult:
 
     def record_eval(it):
         eval_iters.append(it)
-        map_curve.append(float(mean_ap(w_all, jnp.asarray(labeled))))
+        map_curve.append(float(mean_ap(w_all, jnp.asarray(labeled), x,
+                                       labels)))
 
     record_eval(0)
     try:

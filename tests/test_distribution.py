@@ -157,7 +157,6 @@ def test_compressed_psum_error_feedback():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from repro.core.search import shard_map_compat
         from repro.optim.grad_compress import compressed_psum, init_residuals
         mesh = jax.make_mesh((4,), ("dp",))
         g = {"w": jnp.asarray(np.random.default_rng(0)
@@ -165,9 +164,9 @@ def test_compressed_psum_error_feedback():
         r0 = {"w": jnp.zeros((256,), jnp.float32)}
         def f(gs, rs):
             return compressed_psum(gs, rs, "dp")
-        out = jax.jit(shard_map_compat(f, mesh=mesh,
-                                       in_specs=(P("dp"), P()),
-                                       out_specs=P()))(
+        out = jax.jit(jax.shard_map(f, mesh=mesh,
+                                    in_specs=(P("dp"), P()),
+                                    out_specs=P(), check_vma=False))(
             {"w": g["w"]}, r0)
         mean_g, new_r = out
         exact = np.asarray(g["w"]).reshape(4, 256).mean(0)
@@ -190,10 +189,13 @@ def test_dryrun_cell_reduced_mesh():
         m = importlib.import_module("repro.launch.dryrun")
         # monkeypatch the production mesh to the debug size
         import jax
+        from jax.sharding import AxisType
         import repro.launch.dryrun as dr
+        auto = lambda k: (AxisType.Auto,) * k   # as make_production_mesh
         dr.make_production_mesh = lambda multi_pod=False: (
-            jax.make_mesh((2, 2, 2), ("pod", "data", "model")) if multi_pod
-            else jax.make_mesh((4, 2), ("data", "model")))
+            jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                          axis_types=auto(3)) if multi_pod
+            else jax.make_mesh((4, 2), ("data", "model"), axis_types=auto(2)))
         rec = dr.run_cell("qwen3-1.7b", "train_4k", False, None)
         assert rec["flops_per_device"] > 0
         assert rec["memory"]["peak_bytes"] > 0
