@@ -102,8 +102,6 @@ class QueryResult:
     margin: float
     candidates: np.ndarray        # short-list scanned
     nonempty: bool                # did the hash lookup return anything?
-    lookup_s: float
-    rerank_s: float
 
 
 class HyperplaneIndex:
@@ -165,20 +163,17 @@ class HyperplaneIndex:
         """Paper query path: flip-code table lookup + exact-margin re-rank."""
         cfg = self.config
         w = jnp.asarray(w, jnp.float32)
-        t0 = time.perf_counter()
         qcode = np.asarray(self.family.hash_query(w[None, :]))[0]
         cand = self.table.lookup(qcode, cfg.radius, cfg.max_candidates,
                                  cfg.min_candidates)
-        t1 = time.perf_counter()
         if cand.size == 0:
-            return QueryResult(-1, float("inf"), cand, False, t1 - t0, 0.0)
+            return QueryResult(-1, float("inf"), cand, False)
         if cfg.rerank:
             margins, ids = margin_rerank(self.x, w, jnp.asarray(cand), 1)
             idx, margin = int(ids[0]), float(margins[0])
         else:
             idx, margin = int(cand[0]), float("nan")
-        t2 = time.perf_counter()
-        return QueryResult(idx, margin, cand, True, t1 - t0, t2 - t1)
+        return QueryResult(idx, margin, cand, True)
 
     def query_scan(self, w, l: int = 16):
         """Device-side scan path (no table): top-l by Hamming distance, then

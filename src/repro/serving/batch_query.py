@@ -24,6 +24,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.functions import BHHash, SeededBHHash, bilinear_signs
 from repro.core.search import margin_rerank_batch
@@ -77,17 +78,18 @@ def hash_queries_all(families, w, use_kernels: bool = False) -> jax.Array:
     h(P_w) = -h(w) is the packed-bit complement of the database-style
     codes (sgn flips every bit: prod >= 0 pairs exactly with prod < 0
     under the sgn(0)=+1 convention), so the result is bit-identical to
-    the stacked jnp path.
+    the stacked jnp path.  Runs under the ``repro.hash`` host span.
     """
-    w = jnp.asarray(w, jnp.float32)
-    if use_kernels and _seed_stackable(families):
-        return flip_packed(_seeded_grouped_codes(families, w),
-                           families[0].k)
-    if _stackable(families):
-        u = jnp.stack([f.u for f in families])
-        v = jnp.stack([f.v for f in families])
-        return _bh_query_codes(u, v, w)
-    return jnp.stack([f.hash_query(w) for f in families])
+    with TraceAnnotation("repro.hash"):
+        w = jnp.asarray(w, jnp.float32)
+        if use_kernels and _seed_stackable(families):
+            return flip_packed(_seeded_grouped_codes(families, w),
+                               families[0].k)
+        if _stackable(families):
+            u = jnp.stack([f.u for f in families])
+            v = jnp.stack([f.v for f in families])
+            return _bh_query_codes(u, v, w)
+        return jnp.stack([f.hash_query(w) for f in families])
 
 
 def hash_database_all(families, x, use_kernels: bool = False) -> jax.Array:

@@ -515,7 +515,6 @@ class ShardReplicaRouter:
         mask over GLOBAL stable-id space, as in the single-index paths."""
         w = np.atleast_2d(np.asarray(w, np.float32))
         b = w.shape[0]
-        t0 = time.perf_counter()
         self._probe_down_replicas()
         with self._mu:
             if self._shard_x[0] is None:
@@ -535,7 +534,7 @@ class ShardReplicaRouter:
                                 np.full((b, topk), np.inf, np.float32),
                                 np.zeros(b, bool),
                                 [np.empty(0, np.int64) for _ in range(b)],
-                                time.perf_counter() - t0, 0.0, hits, 1.0)
+                                hits, 1.0)
         # phase 1: parallel per-shard scans with failover ladders
         want = [s for s in range(self.shards) if live[s] > 0]
         futs = {s: self._shard_pool.submit(
@@ -551,8 +550,6 @@ class ShardReplicaRouter:
                 continue
             scans[s] = (d, g)
             served[s] = r
-        lookup_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
         # phases 2+3, re-run with a shard dropped if its re-rank fails too
         covered = sorted(scans)
         while covered:
@@ -596,8 +593,7 @@ class ShardReplicaRouter:
                                 np.full((b, topk), np.inf, np.float32),
                                 np.zeros(b, bool),
                                 [np.empty(0, np.int64) for _ in range(b)],
-                                lookup_s, time.perf_counter() - t0, hits,
-                                0.0)
+                                hits, 0.0)
         # phase 3: global top-k by ascending (margin, gid) — the exact tie
         # order lax.top_k realises over an ascending-by-id candidate axis
         mask_arr = None if mask is None else np.asarray(mask, dtype=bool)
@@ -620,19 +616,17 @@ class ShardReplicaRouter:
         hits = (g_m >= 0).sum(axis=(1, 2)).astype(np.int64)
         coverage = sum(live[s] for s in covered) / total_live
         return self._finish(b, topk, ids_topk, margins_topk,
-                            sel_valid.any(axis=1), cands, lookup_s,
-                            time.perf_counter() - t0, hits, coverage)
+                            sel_valid.any(axis=1), cands, hits, coverage)
 
     def _finish(self, b, topk, ids_topk, margins_topk, nonempty, cands,
-                lookup_s, rerank_s, hits, coverage) -> BatchQueryResult:
+                hits, coverage) -> BatchQueryResult:
         degraded = coverage < 1.0
         with self._mu:
             self.last_coverage = float(coverage)
             if degraded:
                 self.degraded_answers += 1
         return BatchQueryResult(
-            ids_topk[:, 0], margins_topk[:, 0], nonempty, cands,
-            lookup_s, rerank_s, hits,
+            ids_topk[:, 0], margins_topk[:, 0], nonempty, cands, hits,
             ids_topk=ids_topk if topk > 1 else None,
             margins_topk=margins_topk if topk > 1 else None,
             coverage=float(coverage), degraded=degraded)

@@ -807,11 +807,10 @@ class LSMMultiTableIndex(MultiTableIndex):
         promises.  Order-preserving: ids ascend with rows, so probe order
         and union first-occurrence order both map through unchanged."""
         with self._lock:
-            cands, hits, secs = super().lookup_batch(w, qcodes)
-            t0 = time.perf_counter()
+            cands, hits = super().lookup_batch(w, qcodes)
             cands = [self.ids_to_rows(c) if c.size else c.astype(np.int64)
                      for c in cands]
-            return cands, hits, secs + time.perf_counter() - t0
+            return cands, hits
 
     def rerank_rows(self, w, cands: list[np.ndarray], l: int = 1,
                     mask_rows=None):
@@ -903,8 +902,6 @@ class LSMMultiTableIndex(MultiTableIndex):
         self._require_fit("query_scan_batch")
         w = np.atleast_2d(np.asarray(w, np.float32))
         b = w.shape[0]
-        t0 = time.perf_counter()
-        hits = np.zeros(self.num_tables, dtype=np.int64)
         cfg = self.config
         with self._lock:
             split = self._base_len
@@ -919,7 +916,7 @@ class LSMMultiTableIndex(MultiTableIndex):
                     np.full(b, -1, np.int64), np.full(b, np.inf, np.float32),
                     np.zeros(b, dtype=bool),
                     [np.empty(0, np.int64) for _ in range(b)],
-                    time.perf_counter() - t0, 0.0, hits,
+                    np.zeros(self.num_tables, dtype=np.int64),
                     ids_topk=ids_pad if topk > 1 else None,
                     margins_topk=m_pad if topk > 1 else None)
             base_dead = split - int(active_view[:split].sum())
@@ -975,9 +972,6 @@ class LSMMultiTableIndex(MultiTableIndex):
             np.asarray(mask, dtype=bool)[ids_view])
         valid = uniq if mask_rows is None else (
             uniq & jnp.asarray(mask_rows)[grows])
-        lookup_s = time.perf_counter() - t0
-
-        t0 = time.perf_counter()
         margins, top = self._rerank_dev(
             jnp.asarray(w, jnp.float32), grows, valid, topk,
             base_x, delta_x, split, delta_len)
@@ -995,11 +989,9 @@ class LSMMultiTableIndex(MultiTableIndex):
         grows_np, valid_np = np.asarray(grows), np.asarray(valid)
         uniq_np = np.asarray(uniq)
         cands = [ids_view[grows_np[i, uniq_np[i]]] for i in range(b)]
-        rerank_s = time.perf_counter() - t0
         self._maybe_compact()
         return BatchQueryResult(
-            top_ids[:, 0], margins[:, 0], valid_np.any(axis=1), cands,
-            lookup_s, rerank_s, hits,
+            top_ids[:, 0], margins[:, 0], valid_np.any(axis=1), cands, hits,
             ids_topk=top_ids if topk > 1 else None,
             margins_topk=margins if topk > 1 else None)
 

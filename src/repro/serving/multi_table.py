@@ -23,6 +23,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -42,8 +43,6 @@ class BatchQueryResult:
     margins: np.ndarray      # (B,) f32
     nonempty: np.ndarray     # (B,) bool — any candidate survived the lookup?
     candidates: list[np.ndarray]  # per-query short-lists (union over tables)
-    lookup_s: float
-    rerank_s: float
     table_hits: np.ndarray   # (L,) per-table yield: probe path = bucket
                              # candidates found; scan path = scanned top-l
                              # slots (B·min(l, n_live), uniform by design)
@@ -55,6 +54,13 @@ class BatchQueryResult:
     # over every live row, so the defaults make this a no-op for them.
     coverage: float = 1.0
     degraded: bool = False
+
+
+def _fetch(x) -> np.ndarray:
+    """One blocking device-to-host read, under its own ``repro.fetch``
+    span."""
+    with TraceAnnotation("repro.fetch"):
+        return np.asarray(x)
 
 
 class MultiTableIndex:
@@ -299,18 +305,17 @@ class MultiTableIndex:
     # -- lookup / query ------------------------------------------------------
 
     def lookup_batch(self, w, qcodes: np.ndarray | None = None
-                     ) -> tuple[list[np.ndarray], np.ndarray, float]:
+                     ) -> tuple[list[np.ndarray], np.ndarray]:
         """Hash + multi-probe for B hyperplanes at once.
 
         qcodes: optional precomputed (L, B, W) query codes (the service
         computes them for its cache keys — no point hashing twice).
         Returns (per-query unioned candidate lists IN ROW SPACE — callers
         must translate with ``rows_to_ids`` before reporting, as the
-        service does — per-table hit counts, elapsed seconds)."""
+        service does — and per-table hit counts)."""
         self._require_fit("lookup_batch")
         cfg = self.config
         w = np.atleast_2d(np.asarray(w, np.float32))
-        t0 = time.perf_counter()
         if qcodes is None:
             qcodes = np.asarray(bq.hash_queries_all(self.families, w))
         hits = np.zeros(self.num_tables, dtype=np.int64)
@@ -325,7 +330,7 @@ class MultiTableIndex:
         cands = [bq.union_candidates(per) for per in per_query]
         if cfg.max_candidates is not None:
             cands = [c[:cfg.max_candidates] for c in cands]
-        return cands, hits, time.perf_counter() - t0
+        return cands, hits
 
     def rerank_rows(self, w, cands: list[np.ndarray], l: int = 1,
                     mask_rows=None):
@@ -342,25 +347,21 @@ class MultiTableIndex:
         mask: optional bool mask over stable-id space — restrict answers to
         these points (AL uses the unlabeled pool; identical to row space
         until the first compaction).  Bit-identical to B calls of `query`."""
-        cands, hits, lookup_s = self.lookup_batch(w)
+        cands, hits = self.lookup_batch(w)
         w = np.atleast_2d(np.asarray(w, np.float32))
-        t0 = time.perf_counter()
         ids, margins, nonempty = self.rerank_rows(w, cands, l,
                                                   self.mask_to_rows(mask))
         ids = self.rows_to_ids(ids)
         cands = [self.rows_to_ids(c) for c in cands]
-        rerank_s = time.perf_counter() - t0
         return BatchQueryResult(ids[:, 0], margins[:, 0], nonempty, cands,
-                                lookup_s, rerank_s, hits,
-                                ids_topk=ids if l > 1 else None,
+                                hits, ids_topk=ids if l > 1 else None,
                                 margins_topk=margins if l > 1 else None)
 
     def query(self, w) -> QueryResult:
         """Single-query path (same machinery, B=1)."""
         res = self.query_batch(np.asarray(w, np.float32)[None, :])
         return QueryResult(int(res.ids[0]), float(res.margins[0]),
-                           res.candidates[0], bool(res.nonempty[0]),
-                           res.lookup_s, res.rerank_s)
+                           res.candidates[0], bool(res.nonempty[0]))
 
     def _scan_state(self, mesh=None, axis: str = "data"):
         """Device-resident stacked live codes for the fused scan: one
@@ -427,12 +428,16 @@ class MultiTableIndex:
         as in query_batch.  Returns a BatchQueryResult interchangeable with
         the host-table query_batch path (candidates come back sorted by id
         rather than in probe order); all reported ids are stable ids.
+
+        Each stage runs under a host span on the profiler's clock:
+        ``repro.hash`` (in ``batch_query.hash_queries_all``), then
+        ``repro.scan``, ``repro.dedup``, ``repro.mask``, ``repro.rerank``,
+        one ``repro.fetch`` per blocking device-to-host read, and
+        ``repro.results`` for the host work after the reads.
         """
         self._require_fit("query_scan_batch")
         w = np.atleast_2d(np.asarray(w, np.float32))
         b = w.shape[0]
-        t0 = time.perf_counter()
-        hits = np.zeros(self.num_tables, dtype=np.int64)
         if not self.active.any():
             ids_pad = np.full((b, topk), -1, np.int64)
             m_pad = np.full((b, topk), np.inf, np.float32)
@@ -440,63 +445,70 @@ class MultiTableIndex:
                 np.full(b, -1, np.int64), np.full(b, np.inf, np.float32),
                 np.zeros(b, dtype=bool),
                 [np.empty(0, np.int64) for _ in range(b)],
-                time.perf_counter() - t0, 0.0, hits,
+                np.zeros(self.num_tables, dtype=np.int64),
                 ids_topk=ids_pad if topk > 1 else None,
                 margins_topk=m_pad if topk > 1 else None)
         codes_dev, live_rows_dev = self._scan_state(mesh, shard_axis)
         n_live = self._live_rows.shape[0]
         qcodes = bq.hash_queries_all(
             self.families, w, use_kernels=self.config.use_kernels)  # (L,B,W)
-        select = self.config.fused_select       # None -> REPRO_FUSED_SELECT
-        pack = self.config.cand_pack            # None -> REPRO_CAND_PACK
-        if mesh is not None:
-            _, idx = hamming_topk_grouped_sharded(
-                codes_dev, qcodes, l, mesh, axis=shard_axis,
-                use_kernel=self.config.use_kernels, n_valid=n_live,
-                select=select, pack=pack)
-        elif self.config.use_kernels:
-            from repro.kernels import ops
-            _, idx = ops.hamming_topk_grouped(codes_dev, qcodes, l,
-                                              select=select, pack=pack)
-        else:
-            _, idx = hamming_topk_grouped(codes_dev, qcodes, l,
-                                          select=select)
+        _, idx = self._scan(codes_dev, qcodes, l, n_live, mesh, shard_axis)
         # device-side union/dedup: per query, sort the L·l live-row ids and
         # invalidate repeats and sentinel (-1) slots.
-        flat = jnp.transpose(idx, (1, 0, 2)).reshape(b, -1)   # (B, L*l)
-        flat = jnp.sort(flat, axis=1)
-        uniq = flat >= 0
-        uniq &= jnp.concatenate(
-            [jnp.ones((b, 1), bool), flat[:, 1:] != flat[:, :-1]], axis=1)
-        grows = live_rows_dev[jnp.clip(flat, 0, n_live - 1)]  # global rows
+        with TraceAnnotation("repro.dedup"):
+            flat = jnp.transpose(idx, (1, 0, 2)).reshape(b, -1)  # (B, L*l)
+            flat = jnp.sort(flat, axis=1)
+            uniq = flat >= 0
+            uniq &= jnp.concatenate(
+                [jnp.ones((b, 1), bool), flat[:, 1:] != flat[:, :-1]],
+                axis=1)
+            grows = live_rows_dev[jnp.clip(flat, 0, n_live - 1)]  # rows
         # mask narrows answers/rerank, but (as in the probe path) NOT the
         # reported candidate short-lists — backends stay interchangeable.
-        mask_rows = self.mask_to_rows(mask)
-        valid = uniq if mask_rows is None else (
-            uniq & jnp.asarray(mask_rows)[grows])
-        lookup_s = time.perf_counter() - t0
+        with TraceAnnotation("repro.mask"):
+            mask_rows = self.mask_to_rows(mask)
+            valid = uniq if mask_rows is None else (
+                uniq & jnp.asarray(mask_rows)[grows])
+        with TraceAnnotation("repro.rerank"):
+            margins, top = margin_rerank_batch(
+                self.x, jnp.asarray(w, jnp.float32), grows, valid, topk)
+        margins, top = _fetch(margins), _fetch(top)
+        hits = _fetch((idx >= 0).sum(axis=(1, 2))).astype(np.int64)
+        grows_np, valid_np = _fetch(grows), _fetch(valid)
+        uniq_np = _fetch(uniq)
+        with TraceAnnotation("repro.results"):
+            top = top.astype(np.int64)
+            top[~np.isfinite(margins)] = -1
+            if margins.shape[1] < topk:   # topk > L*l candidates: pad
+                padw = ((0, 0), (0, topk - margins.shape[1]))
+                margins = np.pad(margins, padw, constant_values=np.inf)
+                top = np.pad(top, padw, constant_values=-1)
+            top = self.rows_to_ids(top)
+            cands = [self.rows_to_ids(grows_np[i, uniq_np[i]])
+                     for i in range(b)]
+            return BatchQueryResult(
+                top[:, 0], margins[:, 0], valid_np.any(axis=1), cands, hits,
+                ids_topk=top if topk > 1 else None,
+                margins_topk=margins if topk > 1 else None)
 
-        t0 = time.perf_counter()
-        margins, top = margin_rerank_batch(
-            self.x, jnp.asarray(w, jnp.float32), grows, valid, topk)
-        margins = np.asarray(margins)
-        top = np.asarray(top).astype(np.int64)
-        top[~np.isfinite(margins)] = -1
-        if margins.shape[1] < topk:   # topk > L*l candidates: pad, not clip
-            padw = ((0, 0), (0, topk - margins.shape[1]))
-            margins = np.pad(margins, padw, constant_values=np.inf)
-            top = np.pad(top, padw, constant_values=-1)
-        top = self.rows_to_ids(top)
-        hits = np.asarray((idx >= 0).sum(axis=(1, 2)), dtype=np.int64)
-        grows_np, valid_np = np.asarray(grows), np.asarray(valid)
-        uniq_np = np.asarray(uniq)
-        cands = [self.rows_to_ids(grows_np[i, uniq_np[i]]) for i in range(b)]
-        rerank_s = time.perf_counter() - t0
-        return BatchQueryResult(
-            top[:, 0], margins[:, 0], valid_np.any(axis=1), cands,
-            lookup_s, rerank_s, hits,
-            ids_topk=top if topk > 1 else None,
-            margins_topk=margins if topk > 1 else None)
+    def _scan(self, codes_dev, qcodes, l: int, n_live: int, mesh,
+              shard_axis: str):
+        """Per-table Hamming top-l of the stacked live codes, (dists, idx)
+        each (L, B, l) on device, under the ``repro.scan`` span: sharded
+        over ``mesh``, through the fused kernel, or in plain jnp."""
+        select = self.config.fused_select       # None -> REPRO_FUSED_SELECT
+        pack = self.config.cand_pack            # None -> REPRO_CAND_PACK
+        with TraceAnnotation("repro.scan"):
+            if mesh is not None:
+                return hamming_topk_grouped_sharded(
+                    codes_dev, qcodes, l, mesh, axis=shard_axis,
+                    use_kernel=self.config.use_kernels, n_valid=n_live,
+                    select=select, pack=pack)
+            if self.config.use_kernels:
+                from repro.kernels import ops
+                return ops.hamming_topk_grouped(codes_dev, qcodes, l,
+                                                select=select, pack=pack)
+            return hamming_topk_grouped(codes_dev, qcodes, l, select=select)
 
     # -- replicated-shard serving hooks (serving.cluster) --------------------
     #
@@ -529,20 +541,8 @@ class MultiTableIndex:
         n_live = self._live_rows.shape[0]
         qcodes = bq.hash_queries_all(
             self.families, w, use_kernels=self.config.use_kernels)
-        select = self.config.fused_select
-        pack = self.config.cand_pack
-        if mesh is not None:
-            dists, idx = hamming_topk_grouped_sharded(
-                codes_dev, qcodes, l, mesh, axis=shard_axis,
-                use_kernel=self.config.use_kernels, n_valid=n_live,
-                select=select, pack=pack)
-        elif self.config.use_kernels:
-            from repro.kernels import ops
-            dists, idx = ops.hamming_topk_grouped(codes_dev, qcodes, l,
-                                                  select=select, pack=pack)
-        else:
-            dists, idx = hamming_topk_grouped(codes_dev, qcodes, l,
-                                              select=select)
+        dists, idx = self._scan(codes_dev, qcodes, l, n_live, mesh,
+                                shard_axis)
         idx_np = np.asarray(idx, dtype=np.int64)
         grows = np.asarray(self._live_rows)[np.clip(idx_np, 0, n_live - 1)]
         ids = np.where(idx_np >= 0, self.ids_np[grows], -1)

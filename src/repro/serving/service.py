@@ -35,9 +35,10 @@ from __future__ import annotations
 
 import contextlib
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.indexer import QueryResult
 from repro.serving import batch_query as bq
@@ -73,9 +74,7 @@ class HashQueryService:
         self.batches = 0
         self.cache_hits = 0
         self.busy_s = 0.0
-        self.lookup_s = 0.0
-        self.rerank_s = 0.0
-        self.latencies_s: list[float] = []
+        self.latencies_s: deque[float] = deque(maxlen=65536)
         self.inserts = 0
         self.inserted_rows = 0
         self.deletes = 0
@@ -173,12 +172,14 @@ class HashQueryService:
     # -- batched path --------------------------------------------------------
 
     def query_batch(self, ws, mask=None) -> list[QueryResult]:
-        """Answer B queries, chunked by ``max_batch``; results in order."""
-        ws = np.atleast_2d(np.asarray(ws, np.float32))
-        out: list[QueryResult] = []
-        for s in range(0, ws.shape[0], self.max_batch):
-            out.extend(self._answer(ws[s:s + self.max_batch], mask))
-        return out
+        """Answer B queries, chunked by ``max_batch``; results in order.
+        The whole call runs under the ``repro.query`` host span."""
+        with TraceAnnotation("repro.query"):
+            ws = np.atleast_2d(np.asarray(ws, np.float32))
+            out: list[QueryResult] = []
+            for s in range(0, ws.shape[0], self.max_batch):
+                out.extend(self._answer(ws[s:s + self.max_batch], mask))
+            return out
 
     def _cache_get(self, key: bytes) -> np.ndarray | None:
         if self._cache_version != self.index.version:
@@ -225,31 +226,26 @@ class HashQueryService:
                 else:
                     cands[i] = hit
                     self.cache_hits += 1
-            lookup_s = 0.0
             if miss_rows:
-                found, _, lookup_s = self.index.lookup_batch(
+                found, _ = self.index.lookup_batch(
                     ws[miss_rows], qcodes=qcodes[:, miss_rows, :])
                 for i, cand in zip(miss_rows, found):
                     cands[i] = cand
                     if use_cache:
                         self._cache_put(keys[i], cand)
 
-            t0 = time.perf_counter()
             ids, margins, nonempty = self.index.rerank_rows(
                 ws, cands, 1, self.index.mask_to_rows(mask))
             ids = self.index.rows_to_ids(ids)
             cands = [self.index.rows_to_ids(c) for c in cands]
-            rerank_s = time.perf_counter() - t0
 
         elapsed = time.perf_counter() - t_start
         self.requests += b
         self.batches += 1
         self.busy_s += elapsed
-        self.lookup_s += lookup_s
-        self.rerank_s += rerank_s
         self.latencies_s.append(elapsed)
         return [QueryResult(int(ids[i, 0]), float(margins[i, 0]), cands[i],
-                            bool(nonempty[i]), lookup_s / b, rerank_s / b)
+                            bool(nonempty[i]))
                 for i in range(b)]
 
     def _answer_scan(self, ws: np.ndarray, mask) -> list[QueryResult]:
@@ -268,13 +264,11 @@ class HashQueryService:
         self.requests += b
         self.batches += 1
         self.busy_s += elapsed
-        self.lookup_s += res.lookup_s
-        self.rerank_s += res.rerank_s
         self.latencies_s.append(elapsed)
-        return [QueryResult(int(res.ids[i]), float(res.margins[i]),
-                            res.candidates[i], bool(res.nonempty[i]),
-                            res.lookup_s / b, res.rerank_s / b)
-                for i in range(b)]
+        with TraceAnnotation("repro.results"):
+            return [QueryResult(int(res.ids[i]), float(res.margins[i]),
+                                res.candidates[i], bool(res.nonempty[i]))
+                    for i in range(b)]
 
     # -- counters ------------------------------------------------------------
 
@@ -290,8 +284,6 @@ class HashQueryService:
             "qps": self.requests / max(self.busy_s, 1e-12),
             "mean_batch_latency_ms": 1e3 * float(lat.mean()),
             "p95_batch_latency_ms": 1e3 * float(np.quantile(lat, 0.95)),
-            "lookup_s": self.lookup_s,
-            "rerank_s": self.rerank_s,
             "index_version": self.index.version,
             "inserts": self.inserts,
             "inserted_rows": self.inserted_rows,
