@@ -68,8 +68,8 @@ class TraceCounter:
 
 def scan_trace_targets() -> dict:
     """The jit entrypoints the serving scan path goes through —
-    query_scan_batch (LSM base+delta), rerank, query hashing, and the
-    lru'd sharded-scan factories."""
+    query_scan_batch (LSM base+delta), query hashing, dedup, mask, rerank,
+    and the lru'd sharded-scan factories."""
     from repro.core import search
     from repro.kernels import ops
     from repro.serving import batch_query as bq
@@ -86,6 +86,9 @@ def scan_trace_targets() -> dict:
         "search._grouped_sharded_fn": search._grouped_sharded_fn,
         "bq._bh_query_codes": bq._bh_query_codes,
         "bq._bh_db_codes": bq._bh_db_codes,
+        "bq._seeded_query_codes": bq._seeded_query_codes,
+        "bq.dedup_candidates": bq.dedup_candidates,
+        "bq.mask_candidates": bq.mask_candidates,
         "ops.bilinear_hash_seeded_grouped": ops.bilinear_hash_seeded_grouped,
     }
 

@@ -21,6 +21,8 @@ as wider rerank gathers.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -47,12 +49,32 @@ def _seed_stackable(families) -> bool:
             and len({f.u.shape for f in families}) == 1)
 
 
+@functools.lru_cache(maxsize=16)
+def _seed_array(seeds: tuple) -> jax.Array:
+    """(L,) uint32 device array of the tables' seeds, built once per family
+    set (keyed by the seed values, so a refresh swap to new families gets
+    its own array)."""
+    return jnp.asarray(seeds, jnp.uint32)
+
+
+def _seeds_of(families) -> jax.Array:
+    return _seed_array(tuple(int(f.seed) for f in families))
+
+
 def _seeded_grouped_codes(families, pts) -> jax.Array:
     """(L, n, W) database-style codes via the grouped seeded kernel: zero
     projection-weight HBM reads, one launch for all L tables."""
     from repro.kernels import ops
-    seeds = jnp.asarray([f.seed for f in families], jnp.uint32)
-    return ops.bilinear_hash_seeded_grouped(pts, seeds, families[0].k)
+    return ops.bilinear_hash_seeded_grouped(pts, _seeds_of(families),
+                                            families[0].k)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _seeded_query_codes(w, seeds, k: int):
+    """(B, d) normals, (L,) seeds -> (L, B, W) query codes: the grouped
+    seeded kernel and the query-side flip as one program."""
+    from repro.kernels import ops
+    return flip_packed(ops.bilinear_hash_seeded_grouped(w, seeds, k), k)
 
 
 @jax.jit
@@ -83,8 +105,8 @@ def hash_queries_all(families, w, use_kernels: bool = False) -> jax.Array:
     with TraceAnnotation("repro.hash"):
         w = jnp.asarray(w, jnp.float32)
         if use_kernels and _seed_stackable(families):
-            return flip_packed(_seeded_grouped_codes(families, w),
-                               families[0].k)
+            return _seeded_query_codes(w, _seeds_of(families),
+                                       families[0].k)
         if _stackable(families):
             u = jnp.stack([f.u for f in families])
             v = jnp.stack([f.v for f in families])
@@ -107,6 +129,37 @@ def hash_database_all(families, x, use_kernels: bool = False) -> jax.Array:
         v = jnp.stack([f.v for f in families])
         return _bh_db_codes(u, v, x)
     return jnp.stack([f.hash_database(x) for f in families])
+
+
+@jax.jit
+def dedup_candidates(idx, live_rows=None, rows=None):
+    """Device-side union/dedup of a scan's per-table top-l, one program.
+
+    idx: (L, B, l) int32 scan ids, -1 in impossible slots.  Per query, the
+    L·l ids are sorted and repeats and sentinels invalidated.  With
+    ``live_rows`` (the monolithic index's live-row map) the ids are scan
+    positions and map through it; without, they are rows already and clip
+    to ``rows`` (a traced count, so a growing row space never retraces).
+    Returns (grows (B, L·l) rows, uniq (B, L·l) bool, hits (L,) live
+    slots per table).
+    """
+    b = idx.shape[1]
+    flat = jnp.sort(jnp.transpose(idx, (1, 0, 2)).reshape(b, -1), axis=1)
+    uniq = flat >= 0
+    uniq &= jnp.concatenate(
+        [jnp.ones((b, 1), bool), flat[:, 1:] != flat[:, :-1]], axis=1)
+    if live_rows is None:
+        grows = jnp.clip(flat, 0, rows - 1)
+    else:
+        grows = live_rows[jnp.clip(flat, 0, live_rows.shape[0] - 1)]
+    return grows, uniq, (idx >= 0).sum(axis=(1, 2))
+
+
+@jax.jit
+def mask_candidates(uniq, grows, mask_rows):
+    """uniq & mask_rows[grows]: the deduplicated candidates the (n,)
+    row-space mask admits, one program."""
+    return uniq & mask_rows[grows]
 
 
 def union_candidates(per_table: list[np.ndarray]) -> np.ndarray:

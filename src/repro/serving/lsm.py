@@ -935,8 +935,9 @@ class LSMMultiTableIndex(MultiTableIndex):
             fams = self.families    # snapshot WITH the device handles: a
             # refresh swap between this block and the hash below must not
             # pair new-generation qcodes with old-generation codes
+        w_dev = jnp.asarray(w)          # one upload for hash and re-rank
         qcodes = bq.hash_queries_all(
-            fams, w, use_kernels=cfg.use_kernels)             # (L, B, W)
+            fams, w_dev, use_kernels=cfg.use_kernels)         # (L, B, W)
         select = cfg.fused_select
         pack = cfg.cand_pack
         d_m = i_m = None
@@ -961,20 +962,14 @@ class LSMMultiTableIndex(MultiTableIndex):
         else:
             delta_x = None
         # device-side union/dedup over global rows — row order == stable-id
-        # order, so this is the same dedup the monolithic scan performs
-        flat = jnp.transpose(i_m, (1, 0, 2)).reshape(b, -1)   # (B, L*l)
-        flat = jnp.sort(flat, axis=1)
-        uniq = flat >= 0
-        uniq &= jnp.concatenate(
-            [jnp.ones((b, 1), bool), flat[:, 1:] != flat[:, :-1]], axis=1)
-        grows = jnp.clip(flat, 0, rows - 1)
+        # order, so this is the monolithic scan's dedup program
+        grows, uniq, hits = bq.dedup_candidates(i_m, rows=rows)
         mask_rows = None if mask is None else (
             np.asarray(mask, dtype=bool)[ids_view])
         valid = uniq if mask_rows is None else (
-            uniq & jnp.asarray(mask_rows)[grows])
+            bq.mask_candidates(uniq, grows, mask_rows))
         margins, top = self._rerank_dev(
-            jnp.asarray(w, jnp.float32), grows, valid, topk,
-            base_x, delta_x, split, delta_len)
+            w_dev, grows, valid, topk, base_x, delta_x, split, delta_len)
         margins = np.asarray(margins)
         top = np.asarray(top).astype(np.int64)
         top[~np.isfinite(margins)] = -1
@@ -985,7 +980,7 @@ class LSMMultiTableIndex(MultiTableIndex):
         live = top >= 0
         top_ids = np.full(top.shape, -1, np.int64)
         top_ids[live] = ids_view[top[live]]
-        hits = np.asarray((i_m >= 0).sum(axis=(1, 2)), dtype=np.int64)
+        hits = np.asarray(hits, dtype=np.int64)
         grows_np, valid_np = np.asarray(grows), np.asarray(valid)
         uniq_np = np.asarray(uniq)
         cands = [ids_view[grows_np[i, uniq_np[i]]] for i in range(b)]

@@ -450,30 +450,23 @@ class MultiTableIndex:
                 margins_topk=m_pad if topk > 1 else None)
         codes_dev, live_rows_dev = self._scan_state(mesh, shard_axis)
         n_live = self._live_rows.shape[0]
+        w_dev = jnp.asarray(w)          # one upload for hash and re-rank
         qcodes = bq.hash_queries_all(
-            self.families, w, use_kernels=self.config.use_kernels)  # (L,B,W)
+            self.families, w_dev, use_kernels=self.config.use_kernels)
         _, idx = self._scan(codes_dev, qcodes, l, n_live, mesh, shard_axis)
-        # device-side union/dedup: per query, sort the L·l live-row ids and
-        # invalidate repeats and sentinel (-1) slots.
         with TraceAnnotation("repro.dedup"):
-            flat = jnp.transpose(idx, (1, 0, 2)).reshape(b, -1)  # (B, L*l)
-            flat = jnp.sort(flat, axis=1)
-            uniq = flat >= 0
-            uniq &= jnp.concatenate(
-                [jnp.ones((b, 1), bool), flat[:, 1:] != flat[:, :-1]],
-                axis=1)
-            grows = live_rows_dev[jnp.clip(flat, 0, n_live - 1)]  # rows
+            grows, uniq, hits = bq.dedup_candidates(idx, live_rows_dev)
         # mask narrows answers/rerank, but (as in the probe path) NOT the
         # reported candidate short-lists — backends stay interchangeable.
         with TraceAnnotation("repro.mask"):
             mask_rows = self.mask_to_rows(mask)
             valid = uniq if mask_rows is None else (
-                uniq & jnp.asarray(mask_rows)[grows])
+                bq.mask_candidates(uniq, grows, mask_rows))
         with TraceAnnotation("repro.rerank"):
-            margins, top = margin_rerank_batch(
-                self.x, jnp.asarray(w, jnp.float32), grows, valid, topk)
+            margins, top = margin_rerank_batch(self.x, w_dev, grows, valid,
+                                               topk)
         margins, top = _fetch(margins), _fetch(top)
-        hits = _fetch((idx >= 0).sum(axis=(1, 2))).astype(np.int64)
+        hits = _fetch(hits).astype(np.int64)
         grows_np, valid_np = _fetch(grows), _fetch(valid)
         uniq_np = _fetch(uniq)
         with TraceAnnotation("repro.results"):

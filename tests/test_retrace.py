@@ -18,7 +18,7 @@ import pytest
 
 from repro.core.indexer import IndexConfig
 from repro.serving import (AsyncHashQueryService, HashQueryService,
-                           LSMMultiTableIndex)
+                           LSMMultiTableIndex, MultiTableIndex)
 
 D = 16
 
@@ -125,6 +125,34 @@ def test_async_ragged_deadline_flushes_no_retrace(trace_counter):
     with trace_counter.assert_no_retrace():
         ragged_round([3, 5, 1, 7, 2, 6, 8, 4])
     svc.close()
+
+
+@pytest.mark.parametrize("use_kernels", [False, True])
+def test_al_scan_rounds_no_retrace(trace_counter, use_kernels):
+    """The active-learning round shape: a scan-mode service over the
+    monolithic index answers a fixed-size batch of NEW hyperplanes under a
+    NEW pool mask every round.  After warm-up, the hash, scan, dedup,
+    mask and re-rank programs add zero traces: w and the mask are traced
+    operands, never cache keys."""
+    rng = np.random.default_rng(11)
+    x = rng.normal(size=(300, D)).astype(np.float32)
+    cfg = IndexConfig(method="bh", bits=20, tables=1, seed=1,
+                      use_kernels=use_kernels)
+    svc = HashQueryService(MultiTableIndex(cfg).fit(x), max_batch=8,
+                           mode="scan", scan_l=16)
+    unlabeled = np.ones(x.shape[0], bool)
+
+    def al_round():
+        res = svc.query_batch(rng.normal(size=(8, D)).astype(np.float32),
+                              mask=unlabeled.copy())
+        picks = [r.index for r in res if r.nonempty]
+        unlabeled[picks] = False
+
+    al_round()                               # warm-up
+    with trace_counter.assert_no_retrace():
+        for _ in range(4):
+            al_round()
+    assert not unlabeled.all()
 
 
 def test_trace_counter_detects_a_real_retrace(trace_counter):

@@ -6,7 +6,8 @@ import pytest
 
 from repro.core.indexer import HyperplaneIndex, IndexConfig
 from repro.data.synthetic import tiny1m_like
-from repro.serving import HashQueryService, MultiTableIndex
+from repro.serving import (HashQueryService, LSMMultiTableIndex,
+                           MultiTableIndex)
 
 BITS, RADIUS = 18, 3
 
@@ -442,3 +443,84 @@ def test_index_stats(corpus):
     assert st["tables"] == 3 and len(st["per_table"]) == 3
     assert st["n"] == corpus.x.shape[0]
     assert all(s["n"] == corpus.x.shape[0] for s in st["per_table"])
+
+
+def _dedup_oracle(index, w, l, topk, mask):
+    """Plain-NumPy dedup, mask and hit count over the index's own per-table
+    top-l (``scan_table_topk``), re-ranked through ``candidate_margins`` so
+    margins share the program's per-row expression; ties break to the
+    lower stable id, as the device re-rank does."""
+    _, ids = index.scan_table_topk(w, l=l)              # (L, B, l) ids
+    b = w.shape[0]
+    hits = (ids >= 0).sum(axis=(1, 2))
+    cands = [np.unique(ids[:, i][ids[:, i] >= 0]) for i in range(b)]
+    width = max(1, max(c.size for c in cands))
+    pad = np.full((b, width), -1, np.int64)
+    for i, c in enumerate(cands):
+        pad[i, :c.size] = c
+    m = index.candidate_margins(w, pad)
+    top = np.full((b, topk), -1, np.int64)
+    top_m = np.full((b, topk), np.inf, np.float32)
+    nonempty = np.zeros(b, bool)
+    for i, c in enumerate(cands):
+        keep = np.ones(c.size, bool) if mask is None else mask[c]
+        nonempty[i] = keep.any()
+        ci, mi = c[keep], m[i, :c.size][keep]
+        order = np.lexsort((ci, mi))[:topk]
+        top[i, :order.size], top_m[i, :order.size] = ci[order], mi[order]
+    return top, top_m, nonempty, cands, hits
+
+
+def _parity_index(kind, tables, state, x):
+    """An index in one of the states the dedup program must handle."""
+    if kind == "lsm":
+        cfg = _cfg(tables=tables, lsm_auto=False)
+        idx = LSMMultiTableIndex(cfg).fit(x[:400])
+        idx.insert(x[400:600])                      # a delta segment
+        idx.delete(np.r_[np.arange(0, 400, 7), np.arange(410, 600, 11)])
+        return idx
+    idx = MultiTableIndex(_cfg(tables=tables,
+                               compact_threshold=None)).fit(x[:600])
+    if state == "heavy_delete":
+        idx.delete(np.flatnonzero(np.arange(600) % 10 != 3))   # 90% dead
+    elif state == "compact":
+        idx.delete(np.arange(0, 600, 2))
+        idx.compact()
+    return idx
+
+
+@pytest.mark.parametrize("kind,tables,state,masked", [
+    ("mono", 1, "fresh", False),
+    ("mono", 1, "fresh", True),
+    ("mono", 3, "fresh", False),
+    ("mono", 3, "fresh", True),
+    ("mono", 1, "heavy_delete", True),
+    ("mono", 3, "heavy_delete", False),
+    ("mono", 1, "compact", False),
+    ("mono", 3, "compact", True),
+    ("lsm", 1, "delta", True),
+    ("lsm", 3, "delta", False),
+    ("lsm", 3, "delta", True),
+])
+def test_scan_dedup_mask_parity_with_numpy(corpus, queries, kind, tables,
+                                           state, masked):
+    """query_scan_batch's compiled dedup, mask and hit count == a plain
+    NumPy oracle of the same semantics: ids, margins, nonempty, candidate
+    lists and table_hits exactly."""
+    idx = _parity_index(kind, tables, state, corpus.x)
+    w = queries[:8]
+    mask = None
+    if masked:
+        mask = np.random.default_rng(7).random(idx._next_id) < 0.5
+    l, topk = 16, 3
+    res = idx.query_scan_batch(w, l=l, topk=topk, mask=mask)
+    top, top_m, nonempty, cands, hits = _dedup_oracle(idx, w, l, topk, mask)
+    assert np.array_equal(res.table_hits, hits)
+    assert np.array_equal(res.nonempty, nonempty)
+    assert np.array_equal(res.ids_topk, top)
+    assert np.array_equal(res.margins_topk, top_m)
+    assert np.array_equal(res.ids, top[:, 0])
+    assert np.array_equal(res.margins, top_m[:, 0])
+    assert len(res.candidates) == len(cands)
+    for got, want in zip(res.candidates, cands):
+        assert np.array_equal(got, want)
