@@ -7,6 +7,8 @@ on the host, so a 1.06M-row corpus costs a fraction of a second of set-up.
 What changed from the host generators is listed under ``assumed`` in each
 configuration file.  A configuration names its generator in
 ``corpus.generator``; the keyword arguments are the rest of that object.
+These two are the built-ins (``GENERATORS``); any other name is a file
+``bench/generators/<name>.py`` (``harness.make_corpus``).
 """
 from __future__ import annotations
 
@@ -97,11 +99,7 @@ def newsgroups(key, *, n: int, d: int, classes: int, topics_per_class: int,
 GENERATORS = {"tiny1m": tiny1m, "newsgroups": newsgroups}
 
 
-def make(corpus_cfg: dict, seed: int):
-    """(x, y) on the default device for a configuration's ``corpus``
-    object and a run seed (any whole number; folded into 32 bits)."""
-    kw = dict(corpus_cfg)
-    gen = GENERATORS[kw.pop("generator")]
-    key = jax.random.fold_in(jax.random.PRNGKey(0), seed % (1 << 32))
-    x, y = gen(key, **kw)
-    return x, y
+def seed_key(seed: int):
+    """The corpus's key for a run seed (any whole number; folded into 32
+    bits)."""
+    return jax.random.fold_in(jax.random.PRNGKey(0), seed % (1 << 32))

@@ -4,7 +4,17 @@ a traced window, and the comparison with the plain reference.
 Everything a cell is made of is found by name: the cell in
 ``BENCHMARK.json``, its configuration in ``bench/configs/<config>.json``,
 its traffic mix in ``bench/traffic/<traffic>.json`` and each per-layer
-metric's reader in ``bench/metrics/<metric>.py``.
+metric's reader in ``bench/metrics/<metric>.py``.  A configuration's corpus
+generator is a built-in of ``corpora.py`` or ``bench/generators/<name>.py``
+(``make(key, mesh, **kw) -> (x, y)``), and its plain reference is
+``reference.py`` or, where it names ``"reference": "<name>"``,
+``bench/references/<name>.py`` (the interface is in ``reference.py``).
+
+Placement: a generator file gets a mesh over the cell's first ``chips``
+devices with one axis, ``"rows"``, and lays the corpus out over it (the
+built-ins make theirs on the default device).  The corpus goes to the
+program as the generator returned it: the harness gathers nothing, and the
+program reads any sharding from ``x.sharding``.
 """
 from __future__ import annotations
 
@@ -12,6 +22,7 @@ import gc
 import importlib.util
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -20,6 +31,7 @@ from types import SimpleNamespace
 
 import jax
 import numpy as np
+from jax.sharding import Mesh
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -66,21 +78,56 @@ def cell_of(config: str, traffic: str, root: str = ROOT, cell=None,
     mix = read_json(os.path.join(root, "bench", "traffic", traffic + ".json"))
     if mix["loop"] != "closed_al":
         raise ValueError(f"traffic {traffic!r}: no loop {mix['loop']!r}")
+    cfg = read_json(os.path.join(root, path))
+    ref = reference if "reference" not in cfg else \
+        load_module("references", cfg["reference"], root)
     return SimpleNamespace(
         root=root, cell=cell or {"name": f"{config}.{traffic}", "chips": 1},
-        cfg=read_json(os.path.join(root, path)), mix=mix,
+        cfg=cfg, mix=mix, reference=ref,
         end_to_end=list(end_to_end) or [{"name": "setup_s", "unit": "s"}],
         per_layer=list(per_layer),
         peaks_path=os.path.join(root, "bench", "peaks.json"))
 
 
-def metric_reader(name: str, root: str = ROOT):
-    path = os.path.join(root, "bench", "metrics", name + ".py")
+def load_module(kind: str, name: str, root: str = ROOT):
+    """``bench/<kind>/<name>.py`` as a module: a metric's reader, a corpus
+    generator or a reference."""
+    path = os.path.join(root, "bench", kind, name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no {kind} file {path}")
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        f"bench_{kind}_" + re.sub(r"\W", "_", name), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def metric_reader(name: str, root: str = ROOT):
+    return load_module("metrics", name, root)
+
+
+def make_corpus(c: SimpleNamespace, seed: int):
+    """(x, y) from the configuration's ``corpus`` object and the run seed.
+    A built-in generator makes them on the default device, as it always
+    has; a file ``bench/generators/<generator>.py`` gets a mesh with one
+    axis, ``"rows"``, over the cell's first ``chips`` devices, and lays
+    them out over it as it chooses."""
+    kw = dict(c.cfg["corpus"])
+    name = kw.pop("generator")
+    key = corpora.seed_key(seed)
+    if name in corpora.GENERATORS:
+        return corpora.GENERATORS[name](key, **kw)
+    mesh = Mesh(np.asarray(jax.devices()[:c.cell["chips"]]), ("rows",))
+    return load_module("generators", name, c.root).make(key, mesh, **kw)
+
+
+def index_config(c: SimpleNamespace, seed: int):
+    """Every key of the configuration's ``index`` but ``scan_l`` (the
+    service's) goes to ``IndexConfig``, which refuses a key it does not
+    have; ``seed`` and ``batch`` are the harness's own."""
+    from repro.core.indexer import IndexConfig
+    ix = {k: v for k, v in c.cfg["index"].items() if k != "scan_l"}
+    return IndexConfig(**ix, seed=index_seed(seed), batch=c.mix["max_batch"])
 
 
 def device_peaks(peaks_path: str, kind: str) -> dict:
@@ -132,33 +179,33 @@ def setup(c: SimpleNamespace, seed: int, control: bool = False,
     shape of the cell's traffic compiled.  ``control`` puts the reference's
     lower-precision twin in the program's place; ``wrap`` (tests) wraps the
     service's ``query_batch``."""
+    if control and not hasattr(c.reference, "Control"):
+        raise ValueError(
+            f"--control 1: the reference of {c.cfg['name']} "
+            f"({c.reference.__file__}) defines no Control")
     phase = {"start": time.perf_counter()}
-    from repro.core.indexer import IndexConfig
     from repro.serving.multi_table import MultiTableIndex
     from repro.serving.service import HashQueryService
 
     cfg, mix = c.cfg, c.mix
     phase["program_import"] = time.perf_counter()
     rng = np.random.default_rng([seed % (1 << 63), 1])
-    x, y = corpora.make(cfg["corpus"], seed)
+    x, y = make_corpus(c, seed)
     x.block_until_ready()
     phase["corpus"] = time.perf_counter()
     classes = cfg["corpus"]["classes"]
     pool = loadgen.hyperplane_pool(x, y, classes, mix["hyperplanes"], seed)
     phase["pool"] = time.perf_counter()
-    ix = cfg["index"]
-    icfg = IndexConfig(method=ix["method"], bits=ix["bits"],
-                       radius=ix["radius"], tables=ix["tables"],
-                       seed=index_seed(seed), batch=mix["max_batch"])
-    index = MultiTableIndex(icfg).fit(x)
+    index = MultiTableIndex(index_config(c, seed)).fit(x)
     phase["fit"] = time.perf_counter()
     r = SimpleNamespace(x=x, pool=pool, rng=rng, index=index)
     r.service = HashQueryService(index, max_batch=mix["max_batch"],
-                                 mode=mix["backend"], scan_l=ix["scan_l"])
+                                 mode=mix["backend"],
+                                 scan_l=cfg["index"]["scan_l"])
     r.initial = loadgen.initial_unlabeled(
         np.asarray(y), classes, mix["labeled_per_class"], rng)
     if control:
-        r.service = reference.Control(x, cfg, index_seed(seed))
+        r.service = c.reference.Control(x, cfg, index_seed(seed))
     if wrap is not None:
         r.service = wrap(r.service)
     warm_up(r)
@@ -235,9 +282,8 @@ def answers_to_check(c: SimpleNamespace, r: SimpleNamespace,
 
 
 def check(c: SimpleNamespace, x, answers: list, seed: int) -> dict:
-    ref = reference.Reference(x, c.cfg, index_seed(seed))
-    x_host = np.asarray(x)
-    got = reference.compare(answers, ref, x_host)
+    ref = c.reference.Reference(x, c.cfg, index_seed(seed))
+    got = reference.compare(answers, ref, x.shape[0])
     lim = c.cfg["limits"]
     got["correct"] = got["checked"] > 0 and all(
         got[k] <= v for k, v in lim.items())

@@ -26,6 +26,29 @@ What one answer is compared on (one table, ``index.tables == 1``):
 ``Control`` is the same pipeline put in the program's place at the next
 lower precision (float8 e4m3 hash operands, bfloat16 re-rank operands); its
 answers have to fail the comparison.
+
+This module is every configuration's reference unless the configuration
+names ``"reference": "<name>"``; the harness then loads
+``bench/references/<name>.py`` in its place.  Such a file, like this one,
+imports nothing of the program and defines:
+
+- ``Reference(x, cfg, index_seed)``: built once after the window, over the
+  corpus ``x`` exactly as the generator laid it out (on one device or
+  sharded), the configuration's dict and the index's seed.
+- ``Reference.topl_bad(ws, candidates)``: per query (rows of ``ws``, float32
+  host array), how many of the program's candidates (a list of int arrays
+  of row ids) cannot be its top-l; an int64 host array.
+- ``Reference.margins(w, rows)``: the float64 margins ``|w.x|/||w||`` of the
+  rows named (a sorted int64 host array of valid row ids) and each row's
+  float32-rounding unit ``2^-24 * sum|x_i w_i| / ||w||``, as two float64 host
+  arrays.  ``compare`` reads margins only through it, so a reference never
+  needs the corpus on the host.
+- optionally ``Control(x, cfg, index_seed)``: the reference one precision
+  lower in the program's place (``query_batch(ws, mask)`` and ``stats()``,
+  as ``HashQueryService``); ``--control 1`` refuses a reference without it.
+
+``compare`` below stays the harness's: it decides ``correct`` for every
+reference.
 """
 from __future__ import annotations
 
@@ -176,6 +199,7 @@ class Reference:
             raise ValueError("the reference compares one table")
         if x.shape[0] >= 1 << ROW_BITS:
             raise ValueError("row ids must fit the sort key")
+        self.x = x
         self.bits = idx["bits"]
         self.l = min(idx["scan_l"], x.shape[0])
         self.rounding = rounding or cfg["precision"]["hash_operands"]
@@ -212,25 +236,40 @@ class Reference:
                 bad[s + i] += self.l - int(in_s[i, top[i]].sum())
         return bad
 
+    def margins(self, w, rows: np.ndarray):
+        """``margins64`` of ``rows``, gathered from the corpus on the device
+        (padded to a power of two, so a few gather shapes serve every
+        answer) and read back as they are."""
+        pad = np.zeros(max(8, 1 << (len(rows) - 1).bit_length()), np.int32)
+        pad[:len(rows)] = rows
+        return margins64(np.asarray(_take(self.x, pad))[:len(rows)], w)
 
-def margins64(x_host: np.ndarray, w: np.ndarray, rows: np.ndarray):
-    """(float64 margins |w.x|/||w||, float32-rounding unit) of rows."""
-    xs = x_host[rows].astype(np.float64)
+
+@jax.jit
+def _take(x, rows):
+    return x[rows]
+
+
+def margins64(xs: np.ndarray, w: np.ndarray):
+    """(float64 margins |w.x|/||w||, float32-rounding unit) of the float32
+    rows xs."""
+    xs = xs.astype(np.float64)
     w64 = np.asarray(w, np.float64)
     nw = max(np.linalg.norm(w64), 1e-12)
     return np.abs(xs @ w64) / nw, U32 * (np.abs(xs) @ np.abs(w64)) / nw
 
 
-def compare(answers: list, ref: Reference, x_host: np.ndarray) -> dict:
-    """The compared numbers over a list of answers.  Each answer has ``w``,
-    ``mask`` (bool over rows, or None), and the program's ``index``,
-    ``margin``, ``nonempty`` and ``candidates``."""
+def compare(answers: list, ref, n: int) -> dict:
+    """The compared numbers over a list of answers, against a reference as
+    the module docstring describes, over a corpus of ``n`` rows.  Each
+    answer has ``w``, ``mask`` (bool over rows, or None), and the program's
+    ``index``, ``margin``, ``nonempty`` and ``candidates``."""
     ws = np.stack([a.w for a in answers]).astype(np.float32)
     bad = ref.topl_bad(ws, [a.candidates for a in answers])
     pick_bad, gap, merr = 0, 0.0, 0.0
     for a in answers:
         c = np.unique(np.asarray(a.candidates, np.int64))
-        c = c[(c >= 0) & (c < x_host.shape[0])]
+        c = c[(c >= 0) & (c < n)]
         valid = c if a.mask is None else c[a.mask[c]]
         if valid.size == 0:
             pick_bad += int(bool(a.nonempty))
@@ -238,7 +277,7 @@ def compare(answers: list, ref: Reference, x_host: np.ndarray) -> dict:
         if not a.nonempty or a.index not in set(valid.tolist()):
             pick_bad += 1
             continue
-        m, unit = margins64(x_host, a.w, valid)
+        m, unit = ref.margins(a.w, valid)
         at = int(np.flatnonzero(valid == a.index)[0])
         best = int(np.argmin(m))
         gap = max(gap, (m[at] - m[best]) / (unit[at] + unit[best]))

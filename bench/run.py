@@ -14,7 +14,8 @@ There is no CPU fallback: without a TPU, or with fewer chips than the cell
 asks for, it exits with code 3 and prints no result.
 
 ``--control 1`` puts the reference's lower-precision twin in the program's
-place (its runs have to come out not correct); the benchmark's own runs
+place (its runs have to come out not correct; a configuration whose
+reference defines no ``Control`` refuses it); the benchmark's own runs
 never pass it.
 """
 from __future__ import annotations

@@ -7,6 +7,8 @@ import copy
 import numpy as np
 import pytest
 
+import harness
+import reference
 from small import run_small, small_cell
 
 
@@ -72,3 +74,28 @@ def test_fault_is_not_correct(fault):
     out = run_small(small_cell("tiny1m.al-scan"),
                     wrap=lambda s: Faulty(s, fault))
     assert out["correct"] is False, out["compared"]
+
+
+@pytest.mark.parametrize("workload,control", [("tiny1m.al-scan", False),
+                                              ("newsgroups.al-scan", True)])
+def test_gathered_margins_match_a_host_copy(workload, control, monkeypatch):
+    """The reference reads margins from the rows it gathers on the device;
+    the compared numbers are those of the whole corpus copied to the host,
+    to the last bit."""
+    seen = []
+    check = harness.check
+
+    def both(c, x, answers, seed):
+        got = dict(check(c, x, answers, seed))
+        ref = c.reference.Reference(x, c.cfg, harness.index_seed(seed))
+        x_host = np.asarray(x)
+        ref.margins = lambda w, rows: reference.margins64(x_host[rows], w)
+        seen.append((got, reference.compare(answers, ref, x_host.shape[0])))
+        return got
+
+    monkeypatch.setattr(harness, "check", both)
+    run_small(small_cell(workload), control=control)
+    (got, host), = seen
+    for k in ("topl_bad", "pick_bad", "gap_units", "margin_units", "checked"):
+        assert got[k] == host[k], k
+    assert host["margin_units"] > 0

@@ -1,6 +1,7 @@
 """The reduction from a trace to per-layer metrics, on a small trace of a
-``tiny1m.al-scan`` window recorded on a v5e (``data/al_trace.json.gz``,
-the events ``reduce.extract`` keeps) and on hand-made intervals."""
+``tiny1m.al-scan`` window recorded on a v5e with the program's stage spans
+(``data/al_trace.json.gz``: a 0.1 s window of six rounds, the events
+``reduce.extract`` keeps) and on hand-made intervals."""
 import os
 
 import pytest
