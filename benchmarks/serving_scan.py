@@ -34,10 +34,11 @@ The deep scan is cheap under histogram selection and reads ~1.0, so the
 regression gate can hold a real floor.
 
 Beyond the fused-vs-unfused comparison this also measures the row-sharded
-scan (``query_scan_batch(mesh=)`` over every local device, answers checked
-against the single-device path) and the delete-churn story: 50%+1 deletes
-trigger auto-compaction, after which QPS and recall are re-measured on the
-survivors (answers must stay inside the survivor id set — ids are stable).
+scan (an index fitted on the corpus row-sharded over every local device,
+answers checked against the single-device path) and the delete-churn
+story: 50%+1 deletes trigger auto-compaction, after which QPS and recall
+are re-measured on the survivors (answers must stay inside the survivor id
+set — ids are stable).
 
 Writes a JSON trajectory record (``BENCH_serving.json``) when ``json_path``
 is given; CI runs this in ``--smoke`` mode and uploads the file as an
@@ -50,6 +51,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import search
 from repro.core.indexer import IndexConfig
@@ -310,13 +313,15 @@ def run(json_path: str | None = None, n: int = 20000, d: int = 64,
         "median_margin_rank": float(np.median(ranks)),
     }
 
-    # -- sharded scan: stacked live codes row-sharded over local devices ----
+    # -- sharded scan: the features row-sharded over local devices ---------
     mesh = jax.make_mesh((jax.device_count(),), ("data",))
-    mt.query_scan_batch(ws, l=l, mesh=mesh)        # warm + build shard layout
+    mt_sh = MultiTableIndex(cfg).fit(jax.device_put(
+        corpus.x, NamedSharding(mesh, P("data", None))))
+    mt_sh.query_scan_batch(ws, l=l)                 # warm the sharded scan
     lat_sh = []
     for _ in range(repeat):
         t0 = time.perf_counter()
-        res_sh = mt.query_scan_batch(ws, l=l, mesh=mesh)
+        res_sh = mt_sh.query_scan_batch(ws, l=l)
         lat_sh.append(time.perf_counter() - t0)
     sharded = {
         "shards": jax.device_count(),

@@ -13,8 +13,9 @@ codes, top-l ids, picks and margins must agree bit for bit, every pick must
 be unlabeled, and its margin (recomputed in float64) must be no smaller
 than the exhaustive minimum.
 
-``--chips 4``: only the multi-chip path, ``HashQueryService(mode="scan",
-mesh=...)`` over one row axis of 4 chips, against the single-chip scan.
+``--chips 4``: only the multi-chip path: an index fitted on the corpus
+row-sharded over 4 chips (``HashQueryService(mode="scan")`` over it),
+against the single-chip index.
 
 Timings printed here are bring-up readings, not benchmark numbers.  Any
 fault raises; the last line of a passing run is one JSON object naming the
@@ -172,11 +173,12 @@ def check_lowering(index, w, unlabeled) -> None:
     seeds = jnp.asarray([f.seed for f in index.families], jnp.uint32)
     hash_txt = ops.bilinear_hash_seeded_grouped.lower(
         jnp.asarray(w), seeds, index.config.bits).as_text()
-    codes_dev, _ = index._scan_state(None, "data")
+    codes_dev, _ = index._scan_state()
     qcodes = bq.hash_queries_all(index.families, w, use_kernels=True)
     active = jnp.asarray(unlabeled[index._live_rows])
-    scan_txt = jax.jit(lambda c, q, a: ops.hamming_topk_grouped(
-        c, q, SCAN_L, active=a)).lower(codes_dev, qcodes, active).as_text()
+    scan_txt = jax.jit(lambda c, q, a: ops.hamming_topk_lanes(
+        c, q, SCAN_L, index._n_live, active=a)).lower(
+        codes_dev, qcodes, active).as_text()
     for name, txt in (("hash", hash_txt), ("scan", scan_txt)):
         check("tpu_custom_call" in txt,
               f"{name} call has no Mosaic kernel")
@@ -234,8 +236,10 @@ def one_chip(seed: int, corpus=None, iters: int = 3, lowering: bool = True):
 
 
 def four_chips(seed: int, corpus=None) -> None:
-    """Row-sharded scan over a 4-chip mesh vs the single-chip scan."""
-    from jax.sharding import AxisType, Mesh
+    """An index fitted on the corpus row-sharded over a 4-chip mesh vs the
+    single-chip index."""
+    from jax.sharding import AxisType, Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
     from repro.core.indexer import IndexConfig
     from repro.serving.multi_table import MultiTableIndex
     from repro.serving.service import HashQueryService
@@ -244,40 +248,52 @@ def four_chips(seed: int, corpus=None) -> None:
           f"--chips 4 needs 4 devices, found {len(devices)}")
     mesh = Mesh(np.array(devices), ("data",), axis_types=(AxisType.Auto,))
     corpus = _tiny1m(seed) if corpus is None else corpus
+    cfg = IndexConfig(method="bh", bits=20, radius=4, seed=seed)
+    index = MultiTableIndex(cfg).fit(corpus.x)
+    xs = jax.device_put(jnp.asarray(corpus.x),
+                        NamedSharding(mesh, P("data", None)))
     t0 = time.perf_counter()
-    index = MultiTableIndex(IndexConfig(method="bh", bits=20, radius=4,
-                                        seed=seed)).fit(corpus.x)
-    log(f"bring-up: index build {time.perf_counter() - t0:.2f}s")
+    sharded_index = MultiTableIndex(cfg).fit(xs)
+    log(f"bring-up: sharded index build {time.perf_counter() - t0:.2f}s")
     rng = np.random.default_rng(seed)
     w = rng.normal(size=(C, corpus.x.shape[1])).astype(np.float32)
     unlabeled = corpus.y < 0
     single = HashQueryService(index, mode="scan", scan_l=SCAN_L)
-    sharded = HashQueryService(index, mode="scan", scan_l=SCAN_L, mesh=mesh)
-    ref = single.query_batch(w, mask=unlabeled)
-    ref_topl = index.scan_table_topk(w, l=SCAN_L)
+    sharded = HashQueryService(sharded_index, mode="scan", scan_l=SCAN_L)
     t0 = time.perf_counter()
-    got = sharded.query_batch(w, mask=unlabeled)
+    sharded.query_batch(w, mask=unlabeled)
     log(f"bring-up: sharded scan first call {time.perf_counter() - t0:.3f}s "
         f"(compile included)")
-    shards = index._codes_dev.addressable_shards
-    placement = sorted(str(s.device) for s in shards)
-    log(f"code shards: {len(index._codes_dev.sharding.device_set)} devices "
+    codes_dev, _ = sharded_index._scan_state()
+    placement = sorted(str(s.device) for s in codes_dev.addressable_shards)
+    log(f"code shards: {len(codes_dev.sharding.device_set)} devices "
         f"{placement}")
     check(len(set(placement)) == 4,
           f"shards not one per chip: {placement}")
-    got_topl = index.scan_table_topk(w, l=SCAN_L, mesh=mesh)
-    mm = {
-        "picks": sum(int(a.index != b.index) for a, b in zip(got, ref)),
-        "margins": sum(int(np.float32(a.margin) != np.float32(b.margin))
-                       for a, b in zip(got, ref)),
-        "candidates": sum(int(not np.array_equal(a.candidates, b.candidates))
-                          for a, b in zip(got, ref)),
-        "topl_dists": int((got_topl[0] != ref_topl[0]).sum()),
-        "topl_ids": int((got_topl[1] != ref_topl[1]).sum()),
-    }
-    log("mismatches sharded vs single-chip: " + json.dumps(mm))
-    check(not any(mm.values()),
-          f"sharded scan differs: {mm}")
+    check(sharded_index.x is xs, "the sharded features were copied")
+    n = corpus.x.shape[0]
+    for step in ("fit", "delete"):
+        if step == "delete":     # a tenth of the rows, flagged dead on chip
+            gone = rng.choice(n, n // 10, replace=False)
+            index.delete(gone)
+            sharded_index.delete(gone)
+        ref = single.query_batch(w, mask=unlabeled)
+        ref_topl = index.scan_table_topk(w, l=SCAN_L)
+        got = sharded.query_batch(w, mask=unlabeled)
+        got_topl = sharded_index.scan_table_topk(w, l=SCAN_L)
+        mm = {
+            "picks": sum(int(a.index != b.index) for a, b in zip(got, ref)),
+            "margins": sum(int(np.float32(a.margin) != np.float32(b.margin))
+                           for a, b in zip(got, ref)),
+            "candidates": sum(int(not np.array_equal(a.candidates,
+                                                     b.candidates))
+                              for a, b in zip(got, ref)),
+            "topl_dists": int((got_topl[0] != ref_topl[0]).sum()),
+            "topl_ids": int((got_topl[1] != ref_topl[1]).sum()),
+        }
+        log(f"mismatches sharded vs single-chip after {step}: "
+            + json.dumps(mm))
+        check(not any(mm.values()), f"sharded scan differs: {mm}")
 
 
 def main() -> int:

@@ -62,9 +62,10 @@ print("post-compaction answers (stable ids):", [r.index for r in post])
 # -- device-side batched Hamming scan (the shardable no-table path) ----------
 # One fused kernel launch covers all 4 tables and the whole batch; the
 # result object is interchangeable with the probe path above.  With more
-# than one device, pass a mesh to row-shard the code stack:
+# than one device, fit on row-sharded features to shard the scan:
 #   mesh = jax.make_mesh((jax.device_count(),), ("data",))
-#   index.query_scan_batch(ws, l=32, mesh=mesh)   # bit-identical answers
+#   xs = jax.device_put(x, NamedSharding(mesh, PartitionSpec("data", None)))
+#   MultiTableIndex(cfg).fit(xs).query_scan_batch(ws, l=32)  # same answers
 scan = index.query_scan_batch(ws[:8], l=32)
 print("scan ids:", scan.ids.tolist())
 

@@ -26,6 +26,44 @@ from repro.utils.bits import hamming_packed
 # the kernels package.
 DIST_SENTINEL = 0x3FFFFFFF
 
+# Lane width of a TPU vreg, and the rows of one (8, 128) uint32 tile of one
+# code word in the lane-dense code layout (see to_lanes).
+LANE = 128
+LANE_TILE = 8 * LANE
+
+
+# Rows a lane-dense table past one block is padded to a multiple of: the
+# largest fused-scan row block (kernels.ops.lane_block, which cuts the table
+# into blocks by the VMEM cap) then divides it.
+LANE_BLOCK = 16 * LANE_TILE
+
+
+def lane_rows(n: int) -> int:
+    """Rows a lane-dense code table of n rows is padded to: whole lane rows
+    up to one tile, whole tiles up to ``LANE_BLOCK``, whole ``LANE_BLOCK``s
+    past it, so every fused-scan block the table is cut into covers whole
+    (8, 128) tiles."""
+    if n <= LANE_TILE:
+        return -(-n // LANE) * LANE
+    step = LANE_TILE if n <= LANE_BLOCK else LANE_BLOCK
+    return -(-n // step) * step
+
+
+def to_lanes(codes, n_pad: int):
+    """(G, n, W) packed codes -> the lane-dense (G, W, n_pad / 128, 128)
+    layout the fused scan kernels read: row r of word w at
+    [g, w, r // 128, r % 128], rows past n zero.  A (G, n, W) operand with
+    W = 1 would fill one lane of each (8, 128) tile — 128x its bytes."""
+    g, n, w = codes.shape
+    codes = jnp.pad(codes, ((0, 0), (0, n_pad - n), (0, 0)))
+    return jnp.swapaxes(codes, 1, 2).reshape(g, w, n_pad // LANE, LANE)
+
+
+def from_lanes(codes, n: int):
+    """Inverse of ``to_lanes``: the first n rows as (G, n, W)."""
+    g, w, r, lane = codes.shape
+    return jnp.swapaxes(codes.reshape(g, w, r * lane), 1, 2)[:, :n]
+
 
 def env_use_kernels() -> bool:
     """Default for the use_kernel(s) knobs: the Pallas kernels run where
@@ -222,11 +260,8 @@ def hamming_topk_grouped_hist(codes, queries, l: int, active=None):
 # contract that makes the merged answer bit-identical to a monolithic scan:
 # ids must be globally comparable (the LSM row space keeps row order ==
 # stable-id order), sentinel slots are (DIST_SENTINEL, -1), and tombstoned
-# rows never reach the top-l — on a single device via the scans' traced
-# ``active`` mask (dead rows rank at the sentinel inside selection), on the
-# sharded path via the slack rule: scan l + (#tombstones) deep, then
-# ``drop_tombstones_topk`` — at most #tombstones of the kept slots can be
-# dead, which makes the surviving top-l exactly the top-l of the live rows.
+# rows never reach the top-l: the scans' traced ``active`` mask ranks dead
+# rows at the sentinel inside selection.
 
 
 @partial(jax.jit, static_argnames=("l",))
@@ -243,25 +278,6 @@ def merge_topk_segments(d_a, i_a, d_b, i_b, l: int):
     """
     d = jnp.concatenate([d_a, d_b], axis=-1)
     i = jnp.concatenate([i_a, i_b], axis=-1)
-    d, i = jax.lax.sort((d, i), dimension=d.ndim - 1, num_keys=2)
-    return _pad_topk(d[..., :l], i[..., :l], l)
-
-
-@partial(jax.jit, static_argnames=("l",))
-def drop_tombstones_topk(dists, ids, active, l: int):
-    """Filter a lex-sorted candidate list down to its top-l LIVE entries.
-
-    active: (n_seg,) bool over the segment's local id space — False rows
-    (tombstones, or padding rows past the segment's true length) are
-    replaced with (DIST_SENTINEL, -1) and sorted out.  The slack contract:
-    the input must be at least ``l + (#inactive rows)`` deep (or cover the
-    whole segment) for the result to equal the top-l of the live rows
-    alone — at most #inactive of the scanned slots can be dead, so l live
-    candidates survive and they are exactly the live top-l.
-    """
-    ok = (ids >= 0) & active[jnp.clip(ids, 0, active.shape[0] - 1)]
-    d = jnp.where(ok, dists, jnp.int32(DIST_SENTINEL))
-    i = jnp.where(ok, ids, jnp.int32(-1))
     d, i = jax.lax.sort((d, i), dimension=d.ndim - 1, num_keys=2)
     return _pad_topk(d[..., :l], i[..., :l], l)
 
@@ -371,41 +387,34 @@ def _sharded_fn(mesh, axis: str, l: int, use_kernel: bool, select: str,
     ))
 
 
-def _grouped_local_then_merge(codes_shard, queries, l: int, l_local: int,
-                              n_valid: int, axis: str, use_kernel: bool,
-                              select: str, pack: str):
+def _sharded_scan_local(codes, queries, active, l: int, n_local: int,
+                        axis: str, use_kernel: bool, select: str, pack: str):
     """Local grouped scan + small all-gather merge for one shard.
 
-    codes_shard: (G, rows, W) — this shard's contiguous row range of every
-    group; queries: (G, B, W) replicated.  Emits the shard's top-l_local
-    per (group, query), carries SHARD-LOCAL ids (narrowed per ``pack``)
-    across the gather, then widens, restores global row ids from each row's
-    gather-axis position, and lex-sorts the S·l_local candidates by
-    (distance, id) so ties resolve to the lowest global id, exactly like
-    the single-device grouped scan.
+    codes: (G, W, lane_rows(n_local) / 128, 128) — this shard's lane-dense
+    block: its n_local rows of every group (contiguous global rows from
+    shard · n_local); queries: (G, B, W) replicated; active: this shard's
+    (n_local,) liveness flags, or None (every row live).  Emits the shard's
+    top-l of its live rows per (group, query), carries SHARD-LOCAL ids
+    (narrowed per ``pack``) across the gather, then widens, restores global
+    row ids from each row's gather-axis position, and lex-sorts the S·l
+    candidates by (distance, id) so ties resolve to the lowest global id,
+    exactly like the single-device grouped scan.
     """
     if use_kernel:
         from repro.kernels import ops
-        cd, ci = ops.hamming_topk_grouped(codes_shard, queries, l_local,
-                                          select=select, pack=pack)
+        cd, ci = ops.hamming_topk_lanes(codes, queries, l, n_local,
+                                        select=select, active=active,
+                                        pack=pack)
     else:
-        cd, ci = hamming_topk_grouped(codes_shard, queries, l_local,
-                                      select=select, pack=pack)
-    rows, w = codes_shard.shape[1], codes_shard.shape[2]
-    offset = jax.lax.axis_index(axis) * rows
-    # rows past the true table end (shard-divisibility padding) turn into
-    # sentinel slots; l_local = l + pad_rows guarantees they could not have
-    # crowded a real global-top-l row out of this shard's local list.  The
-    # padding test needs the global id, but ids stay shard-local across the
-    # gather (they must fit the narrow dtype) — offsets come back in
-    # _widen_gather from the gather-axis position.
-    pad_row = (ci >= 0) & (ci + offset >= n_valid)
-    cd = jnp.where(pad_row, jnp.int32(DIST_SENTINEL), cd)
-    ci = jnp.where(pad_row, -1, ci).astype(jnp.int32)
-    cd, ci, pk_d, pk_i = _narrow_gather(cd, ci, pack, w, rows)
-    all_d = jax.lax.all_gather(cd, axis)          # (S, G, B, l_local)
+        cd, ci = hamming_topk_grouped(from_lanes(codes, n_local), queries,
+                                      l, select=select, active=active)
+    ci = ci.astype(jnp.int32)
+    cd, ci, pk_d, pk_i = _narrow_gather(cd, ci, pack, queries.shape[2],
+                                        n_local)
+    all_d = jax.lax.all_gather(cd, axis)          # (S, G, B, l)
     all_i = jax.lax.all_gather(ci, axis)
-    all_d, all_i = _widen_gather(all_d, all_i, pk_d, pk_i, rows, 0)
+    all_d, all_i = _widen_gather(all_d, all_i, pk_d, pk_i, n_local, 0)
     g, b = queries.shape[0], queries.shape[1]
     all_d = jnp.moveaxis(all_d, 0, 2).reshape(g, b, -1)
     all_i = jnp.moveaxis(all_i, 0, 2).reshape(g, b, -1)
@@ -413,68 +422,56 @@ def _grouped_local_then_merge(codes_shard, queries, l: int, l_local: int,
     return all_d[:, :, :l], all_i[:, :, :l]
 
 
-def hamming_topk_grouped_sharded(codes, queries, l: int, mesh,
-                                 axis: str = "data",
-                                 use_kernel: bool | None = None,
-                                 n_valid: int | None = None,
-                                 select: str | None = None,
-                                 pack: str | None = None):
-    """Distributed grouped top-l scan: the multi-table analogue of
-    ``hamming_topk_sharded``.
+def sharded_topk_lanes(codes, queries, l: int, mesh, axis: str,
+                       n_local: int, active=None,
+                       use_kernel: bool | None = None,
+                       select: str | None = None, pack: str | None = None):
+    """Distributed grouped top-l scan over lane-dense codes laid out
+    row-sharded over mesh axis ``axis``: shard s holds global rows
+    [s · n_local, (s + 1) · n_local) as its own (G, W, lane_rows(n_local) /
+    128, 128) block.  Queries (G, B, W) are replicated.  active: optional
+    (shards · n_local,) bool liveness flags sharded like the rows — False
+    rows (tombstones, or the padding a shard with fewer rows carries) rank
+    at the sentinel inside each local selection, so the result is the
+    top-l of the live rows alone.
 
-    codes: (G, n, W) uint32, row-sharded along dim 1 over mesh axis `axis`
-    (n need not divide the shard count — rows are padded and masked out);
-    queries: (G, B, W) uint32, replicated.  Callers holding an already
-    shard-aligned device array (serving.MultiTableIndex pads host-side
-    before device_put so no resharding happens here) pass ``n_valid`` =
-    the true row count; rows >= n_valid are treated as padding.  Returns
-    replicated (dists (G, B, l), ids (G, B, l)) with ids global to each
-    group's row space, bit-identical to the single-device grouped scan
-    (kernels.ops.hamming_topk_grouped / the pure-jnp fallback) including
-    tie order (lowest global id) and l > n_valid sentinels
-    (DIST_SENTINEL, -1).
-
-    Each shard runs ONE local grouped launch for all G groups x B queries;
-    only the (S, G, B, l_local) candidate pairs cross the interconnect —
-    O(G·B·l·S·8) bytes, independent of n.  l_local = l plus the padding
-    rows a single shard can see: padding is a contiguous tail, so at most
-    one shard mixes real and padding rows, and the extra slots guarantee
-    padding can never crowd a real global-top-l row out of its local list.
+    Each shard runs ONE local grouped launch for all G groups x B queries
+    on its own block; only the (S, G, B, l) candidate pairs cross the
+    interconnect — O(G·B·l·S·8) bytes, independent of n.  Returns
+    replicated (dists (G, B, l), ids (G, B, l)) with global row ids,
+    bit-identical to the single-device grouped scan of the live rows
+    (ties to the lowest global row, (DIST_SENTINEL, -1) in impossible
+    slots).  The program is named ``sharded_scan``.
     """
     if use_kernel is None:
         use_kernel = env_use_kernels()
     select = env_fused_select(select)
     pack = env_cand_pack(pack)
-    g, n, w = codes.shape
-    if n_valid is None:
-        n_valid = n
-    shards = mesh.shape[axis]
-    pad = (-n) % shards
-    if pad:
-        codes = jnp.pad(codes, ((0, 0), (0, pad), (0, 0)))
-    n_pad = n + pad
-    l_local = l + min(n_pad - n_valid, n_pad // shards)
-    fn = _grouped_sharded_fn(mesh, axis, l, l_local, n_valid, use_kernel,
-                             select, pack)
-    return fn(codes, queries)
+    fn = _sharded_scan_fn(mesh, axis, l, n_local, active is not None,
+                          use_kernel, select, pack)
+    return fn(codes, queries) if active is None else fn(codes, queries,
+                                                        active)
 
 
-@lru_cache(maxsize=256)
-def _grouped_sharded_fn(mesh, axis: str, l: int, l_local: int, n_valid: int,
-                        use_kernel: bool, select: str, pack: str):
-    """Jitted shard_map closure for hamming_topk_grouped_sharded, cached so
-    the serving scan hot path doesn't rebuild and re-trace the distributed
-    scan on every micro-batch (n_valid changes per index mutation, so churn
-    rotates cache entries; the LRU bound keeps that in check)."""
-    return jax.jit(jax.shard_map(
-        partial(_grouped_local_then_merge, l=l, l_local=l_local,
-                n_valid=n_valid, axis=axis, use_kernel=use_kernel,
-                select=select, pack=pack),
-        mesh=mesh,
-        in_specs=(P(None, axis, None), P()),
-        out_specs=(P(), P()),
-        check_vma=False,
-    ))
+@lru_cache(maxsize=64)
+def _sharded_scan_fn(mesh, axis: str, l: int, n_local: int, masked: bool,
+                     use_kernel: bool, select: str, pack: str):
+    """The jitted ``sharded_scan`` program, cached so the serving hot path
+    doesn't rebuild and re-trace the distributed scan on every batch."""
+    body = partial(_sharded_scan_local, l=l, n_local=n_local, axis=axis,
+                   use_kernel=use_kernel, select=select, pack=pack)
+    specs = (P(None, None, axis, None), P())
+    if masked:
+        local = jax.shard_map(body, mesh=mesh, in_specs=specs + (P(axis),),
+                              out_specs=(P(), P()), check_vma=False)
+    else:
+        local = jax.shard_map(lambda c, q: body(c, q, None), mesh=mesh,
+                              in_specs=specs, out_specs=(P(), P()),
+                              check_vma=False)
+
+    def sharded_scan(codes, queries, *active):
+        return local(codes, queries, *active)
+    return jax.jit(sharded_scan)
 
 
 @partial(jax.jit, static_argnames=("l",))
@@ -490,26 +487,104 @@ def margin_rerank(x, w, candidates, l: int):
     return -neg, candidates[sel]
 
 
-@partial(jax.jit, static_argnames=("l",))
-def margin_rerank_batch(x, w_batch, candidates, valid, l: int):
-    """Batched exact re-rank: one gather + one batched matmul for B queries.
+def rows_minor(x) -> bool:
+    """True where the device keeps 2-D ``x`` with its rows on the minor
+    (lane) axis — a TPU lays out an (n, d) float32 array with d < 128 or
+    d = 385 that way, to pad less.  An XLA gather of rows from such an
+    array first copies the whole of it into rows-major order."""
+    layout = getattr(getattr(x, "format", None), "layout", None)
+    return tuple(getattr(layout, "major_to_minor", ())) == (1, 0)
+
+
+def gather_rows(x, rows, rows_minor: bool = False):
+    """x[rows]: (n, d) rows at an int array of row ids -> rows.shape + (d,).
+    Where ``rows_minor`` (see ``rows_minor``), each row is a dynamic slice
+    of one column of x.T, which reads x where it lies: a gather would
+    first copy all of x."""
+    if not rows_minor:
+        return x[rows]
+    d = x.shape[1]
+    flat = rows.reshape(-1)
+    xt = x.T
+
+    def one(i, out):
+        col = jax.lax.dynamic_slice(xt, (0, flat[i]), (d, 1))
+        return jax.lax.dynamic_update_slice(out, col, (0, i))
+
+    out = jax.lax.fori_loop(0, flat.shape[0], one,
+                            jnp.zeros((d, flat.shape[0]), x.dtype),
+                            unroll=8)
+    return out.T.reshape(rows.shape + (d,))
+
+
+def _margins(cx, w_batch):
+    """|w.x| / ||w|| of gathered (B, C, d) rows.  Multiply+reduce instead
+    of einsum: the d-reduction order is then independent of B and C, so
+    batched answers are bit-identical to the same queries issued one at a
+    time, and to the same rows re-ranked on another shard."""
+    m = jnp.abs(jnp.sum(cx * w_batch[:, None, :], axis=-1))
+    return m / jnp.maximum(jnp.linalg.norm(w_batch, axis=1, keepdims=True),
+                           1e-12)
+
+
+@partial(jax.jit, static_argnames=("l", "rows_minor"))
+def margin_rerank_batch(x, w_batch, candidates, valid, l: int,
+                        rows_minor: bool = False):
+    """Batched exact re-rank: one gather + one batched reduce for B queries.
 
     x: (n, d) database; w_batch: (B, d) hyperplane normals;
     candidates: (B, C) int ids padded to a common length C;
-    valid: (B, C) bool mask for the padding (False rows rank last).
+    valid: (B, C) bool mask for the padding (False rows rank last);
+    rows_minor: x's device layout (``rows_minor``), which picks the gather.
     Returns (margins (B, l), ids (B, l)) sorted ascending by margin;
     padded-out slots come back with margin +inf and their padded id.
     """
-    cx = x[candidates]                         # (B, C, d) gather
-    # multiply+reduce instead of einsum: the d-reduction order is then
-    # independent of B and C, so batched answers are bit-identical to the
-    # same queries issued one at a time (candidate lists are short — the
-    # VPU path costs nothing over the MXU here).
-    m = jnp.abs(jnp.sum(cx * w_batch[:, None, :], axis=-1))
-    m = m / jnp.maximum(jnp.linalg.norm(w_batch, axis=1, keepdims=True), 1e-12)
-    m = jnp.where(valid, m, jnp.inf)
+    cx = gather_rows(x, candidates, rows_minor)        # (B, C, d)
+    m = jnp.where(valid, _margins(cx, w_batch), jnp.inf)
     neg, sel = jax.lax.top_k(-m, min(l, candidates.shape[1]))
     return -neg, jnp.take_along_axis(candidates, sel, axis=1)
+
+
+def _sharded_rerank_local(x, w_batch, candidates, valid, l: int,
+                          n_local: int, axis: str, rows_minor: bool):
+    """One shard's part of ``sharded_rerank``: the candidates this shard
+    owns gathered from its own block, margins as ``margin_rerank_batch``
+    computes them, +inf where another shard owns the row; the owners'
+    margins combine exactly by a min across shards, then top-l."""
+    local = candidates - jax.lax.axis_index(axis) * n_local
+    own = valid & (local >= 0) & (local < n_local)
+    cx = gather_rows(x, jnp.clip(local, 0, n_local - 1), rows_minor)
+    m = jnp.where(own, _margins(cx, w_batch), jnp.inf)
+    m = jax.lax.pmin(m, axis)
+    neg, sel = jax.lax.top_k(-m, min(l, candidates.shape[1]))
+    return -neg, jnp.take_along_axis(candidates, sel, axis=1)
+
+
+def sharded_rerank(x, w_batch, candidates, valid, l: int, mesh, axis: str,
+                   rows_minor: bool = False):
+    """``margin_rerank_batch`` over features row-sharded over mesh axis
+    ``axis`` (n_local rows a shard): each chip re-ranks the candidates it
+    owns from its own block, so the features never move, and the answers
+    are bit-identical to the one-device re-rank of the same rows.  w_batch,
+    candidates (global rows) and valid are replicated; so are the results.
+    The program is named ``sharded_rerank``."""
+    n_local = x.shape[0] // mesh.shape[axis]
+    return _sharded_rerank_fn(mesh, axis, l, n_local, rows_minor)(
+        x, w_batch, candidates, valid)
+
+
+@lru_cache(maxsize=64)
+def _sharded_rerank_fn(mesh, axis: str, l: int, n_local: int,
+                       rows_minor: bool):
+    local = jax.shard_map(
+        partial(_sharded_rerank_local, l=l, n_local=n_local, axis=axis,
+                rows_minor=rows_minor),
+        mesh=mesh, in_specs=(P(axis, None), P(), P(), P()),
+        out_specs=(P(), P()), check_vma=False)
+
+    def sharded_rerank(x, w_batch, candidates, valid):
+        return local(x, w_batch, candidates, valid)
+    return jax.jit(sharded_rerank)
 
 
 @partial(jax.jit, static_argnames=("l",))
@@ -534,9 +609,7 @@ def margin_rerank_segmented(base_x, delta_x, split, w_batch, candidates,
     cb = base_x[jnp.clip(candidates, 0, base_x.shape[0] - 1)]
     cd = delta_x[jnp.clip(candidates - split, 0, delta_x.shape[0] - 1)]
     cx = jnp.where(is_base[..., None], cb, cd)
-    m = jnp.abs(jnp.sum(cx * w_batch[:, None, :], axis=-1))
-    m = m / jnp.maximum(jnp.linalg.norm(w_batch, axis=1, keepdims=True), 1e-12)
-    m = jnp.where(valid, m, jnp.inf)
+    m = jnp.where(valid, _margins(cx, w_batch), jnp.inf)
     neg, sel = jax.lax.top_k(-m, min(l, candidates.shape[1]))
     return -neg, jnp.take_along_axis(candidates, sel, axis=1)
 
@@ -601,9 +674,7 @@ def margin_batch(x, w_batch, candidates, valid):
     the property the cluster router's cross-shard re-rank leans on.
     """
     cx = x[jnp.clip(candidates, 0, x.shape[0] - 1)]
-    m = jnp.abs(jnp.sum(cx * w_batch[:, None, :], axis=-1))
-    m = m / jnp.maximum(jnp.linalg.norm(w_batch, axis=1, keepdims=True), 1e-12)
-    return jnp.where(valid, m, jnp.inf)
+    return jnp.where(valid, _margins(cx, w_batch), jnp.inf)
 
 
 @jax.jit
@@ -620,6 +691,4 @@ def margin_batch_segmented(base_x, delta_x, split, w_batch, candidates,
     cb = base_x[jnp.clip(candidates, 0, base_x.shape[0] - 1)]
     cd = delta_x[jnp.clip(candidates - split, 0, delta_x.shape[0] - 1)]
     cx = jnp.where(is_base[..., None], cb, cd)
-    m = jnp.abs(jnp.sum(cx * w_batch[:, None, :], axis=-1))
-    m = m / jnp.maximum(jnp.linalg.norm(w_batch, axis=1, keepdims=True), 1e-12)
-    return jnp.where(valid, m, jnp.inf)
+    return jnp.where(valid, _margins(cx, w_batch), jnp.inf)

@@ -16,13 +16,13 @@ Two families of kernels live here:
   matrix costs 2·n·B·4 = 256 bytes/point of HBM round-trip against a
   16-byte/point code table — the scan stops being bandwidth-bound on codes.
 - ``hamming_topk_fused_kernel`` — fuse selection into the scan.  Each grid
-  block popcounts its (block_n, W) tile against its B queries into a
-  (B, block_n) VMEM tile (rows on lanes) and selects the block-local
-  smallest-l candidates there (deterministic ties: lowest row index wins);
-  only (grid, B, l) candidate (distance, row-id) pairs ever reach HBM.  A
-  tiny second-stage merge over grid·l ≪ n rows (see kernels/ops.py) yields
-  the final (B, l) answer, bit-identical to lax.top_k over the full
-  distance matrix.
+  block popcounts its lane-dense code tile (see below) against its B
+  queries into a (B, block_n) VMEM tile (rows on lanes) and selects the
+  block-local smallest-l candidates there (deterministic ties: lowest row
+  index wins); only (grid, B, l) candidate (distance, row-id) pairs ever
+  reach HBM.  A tiny second-stage merge over grid·l ≪ n rows (see
+  kernels/ops.py) yields the final (B, l) answer, bit-identical to
+  lax.top_k over the full distance matrix.
 - ``hamming_topk_hist_kernel`` — same contract, cheaper selection.  The
   argmin kernel pays l rounds of masked argmin over the (B, block_n) tile:
   O(l·block_n·B) VPU work that dominates once HBM traffic is minimized.
@@ -48,6 +48,14 @@ The fused kernels run on a (groups, blocks-per-group) grid: the code table
 may be G stacked sub-tables (multi-table serving stacks L tables of
 n_live rows each) and each block is matched against only its own group's
 B query rows — so an L-table batched query is ONE kernel launch.
+
+The fused kernels read codes LANE-DENSE: a (G, W, n_pad / 128, 128) array
+in which row r of word w sits at [g, w, r // 128, r % 128].  A (G, n, W)
+table with W = 1 word would fill one lane of 128 in every (8, 128) tile
+the kernel's operand must be laid out in — 128x its bytes in HBM — while
+the lane-dense table is exactly its bytes.  ``core.search.to_lanes`` builds
+it; a block is (1, W, block_n / 128, 128), so a block that does not cover
+the whole table needs block_n to be a multiple of 1024 (whole tiles).
 """
 from __future__ import annotations
 
@@ -84,6 +92,13 @@ VMEM_BUDGET_BYTES = 16 * 2**20
 # Lane width of a vreg.  The fused selects keep rows on lanes, so block_n
 # must be a multiple of it (ops._block_rows rounds to it).
 LANE = 128
+
+
+def _lane_spec(w: int, block_n: int):
+    """BlockSpec of one lane-dense (1, W, block_n / 128, 128) code tile on
+    the (groups, blocks) grid."""
+    return pl.BlockSpec((1, w, block_n // LANE, LANE),
+                        lambda t, i: (t, 0, i, 0))
 
 
 def cand_encoding(pack: str, w: int, block_n: int):
@@ -156,8 +171,9 @@ def _batch_kernel(codes_ref, queries_ref, out_ref, *, n_words: int):
 def _topk_fused_kernel(*refs, n_words: int, l: int, block_n: int,
                        n_valid: int, pack: str = "none",
                        masked: bool = False):
-    """One grid step: scan a (block_n, W) code tile against this group's B
-    queries and emit the block-local smallest-l (distance, row-id) pairs.
+    """One grid step: scan a lane-dense (W, block_n / 128, 128) code tile
+    against this group's B queries and emit the block-local smallest-l
+    (distance, row-id) pairs.
 
     The (B, block_n) distance tile lives only in VMEM scratch (``acc_ref``)
     — it is never written to HBM.  Selection is l rounds of masked argmin;
@@ -180,7 +196,7 @@ def _topk_fused_kernel(*refs, n_words: int, l: int, block_n: int,
          out_d_ref, out_i_ref, acc_ref) = refs
     else:
         codes_ref, queries_ref, out_d_ref, out_i_ref, acc_ref = refs
-    acc = _popcount_rows(codes_ref[0].T, queries_ref[0], n_words)
+    acc = _popcount_lanes(codes_ref[0], queries_ref[0], n_words)
     # group-local row ids for this block; rows past the group's live region
     # (block padding) are masked to the sentinel so they always rank last.
     base = pl.program_id(1) * block_n
@@ -218,9 +234,10 @@ def hamming_topk_fused_kernel(codes, queries, l: int, n_valid: int, *,
                               interpret: bool = False, pack: str = "none"):
     """Fused scan+select over G stacked code groups in ONE device launch.
 
-    codes: (G, n_pad, W) uint32 with n_pad % block_n == 0; queries:
-    (G, B, W) uint32; n_valid: live rows per group (rows >= n_valid are
-    padding).  Returns (dists, ids): (G, grid, B, l) block-local
+    codes: (G, W, n_pad / 128, 128) uint32, lane-dense (module docstring),
+    with n_pad % block_n == 0; queries: (G, B, W) uint32; n_valid: live
+    rows per group (rows >= n_valid are padding).  Returns (dists, ids):
+    (G, grid, B, l) block-local
     candidates, ids LOCAL to each block (< block_n — the merge adds the
     block base back); masked slots carry the pack's sentinel.  l must
     satisfy l <= block_n.
@@ -235,16 +252,14 @@ def hamming_topk_fused_kernel(codes, queries, l: int, n_valid: int, *,
     A TRACED operand (its value is not a jit key), so mutable-index serving
     can flip tombstones without recompiling the scan.
     """
-    g, n_pad, w = codes.shape
+    g, w, r, _ = codes.shape
     b = queries.shape[1]
-    grid_n = n_pad // block_n
+    grid_n = r * LANE // block_n
     d_dtype, i_dtype, _ = cand_encoding(pack, w, block_n)
     out_shapes = [jax.ShapeDtypeStruct((g, grid_n, b, l), d_dtype),
                   jax.ShapeDtypeStruct((g, grid_n, b, l), i_dtype)]
-    in_specs = [
-        pl.BlockSpec((1, block_n, w), lambda t, i: (t, i, 0)),
-        pl.BlockSpec((1, b, w), lambda t, i: (t, 0, 0)),
-    ]
+    in_specs = [_lane_spec(w, block_n),
+                pl.BlockSpec((1, b, w), lambda t, i: (t, 0, 0))]
     operands = [codes, queries]
     if active is not None:
         in_specs.append(pl.BlockSpec((1, block_n), lambda t, i: (0, i)))
@@ -277,16 +292,21 @@ def _popcount_tile(codes, queries, n_words: int):
     return acc
 
 
-def _popcount_rows(codes_t, queries, n_words: int):
-    """(W, block_n) codes vs (B, W) queries -> (B, block_n) int32 distances
-    with the rows on lanes: the fused selects reduce over rows, and a
-    (B, block_n) tile fills every lane where a (block_n, B) one would use
-    B of 128."""
-    acc = jnp.zeros((queries.shape[0], codes_t.shape[1]), jnp.int32)
-    for w in range(n_words):
-        acc += _popcount_u32(jnp.bitwise_xor(codes_t[w:w + 1, :],
-                                             queries[:, w:w + 1]))
-    return acc
+def _popcount_lanes(codes, queries, n_words: int):
+    """(W, block_n / 128, 128) lane-dense codes vs (B, W) queries ->
+    (B, block_n) int32 distances with the rows on lanes: the fused selects
+    reduce over rows, and a (B, block_n) tile fills every lane where a
+    (block_n, B) one would use B of 128.  Built one 128-row lane row of the
+    codes at a time, each broadcast over the B query sublanes."""
+    parts = []
+    for j in range(codes.shape[1]):
+        acc = _popcount_u32(jnp.bitwise_xor(codes[0, j:j + 1, :],
+                                            queries[:, 0:1]))
+        for w in range(1, n_words):
+            acc += _popcount_u32(jnp.bitwise_xor(codes[w, j:j + 1, :],
+                                                 queries[:, w:w + 1]))
+        parts.append(acc)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
 def _dot_exact(a, b):
@@ -454,7 +474,7 @@ def _topk_hist_kernel(*refs, n_words: int, l: int, block_n: int,
     else:
         codes_ref, queries_ref, out_d_ref, out_i_ref = refs
         act = None
-    acc = _popcount_rows(codes_ref[0].T, queries_ref[0], n_words)
+    acc = _popcount_lanes(codes_ref[0], queries_ref[0], n_words)
     base = pl.program_id(1) * block_n
     out_d, out_i = _hist_select(acc, base, l, n_valid, max_dist, block_n,
                                 act)
@@ -468,10 +488,9 @@ def _topk_hist_dma_kernel(*refs, n_words: int, l: int,
                           masked: bool = False):
     """Histogram-select step with a double-buffered HBM→VMEM code pipeline.
 
-    The code stack stays in HBM (memory_space=ANY), transposed to
-    (G, W, n_pad) so that a tile's copy slices only the lane-dense row
-    axis (Mosaic refuses a DMA whose last dimension is a sub-128 W); each
-    sequential step of
+    The lane-dense code stack stays in HBM (memory_space=ANY), so a tile's
+    copy slices only whole 128-row lane rows (Mosaic refuses a DMA whose
+    last dimension is a sub-128 W); each sequential step of
     the (G, blocks) grid waits on the async copy of its own tile (started
     by the previous step) and immediately starts the copy of the next tile
     into the other buffer, so the popcount of tile i overlaps the fetch of
@@ -497,7 +516,8 @@ def _topk_hist_dma_kernel(*refs, n_words: int, l: int,
 
     def copy_tile(slot_idx, g_idx, blk_idx):
         return pltpu.make_async_copy(
-            codes_hbm_ref.at[g_idx, :, pl.dslice(blk_idx * block_n, block_n)],
+            codes_hbm_ref.at[g_idx, :, pl.dslice(blk_idx * (block_n // LANE),
+                                                  block_n // LANE)],
             buf_ref.at[slot_idx],
             sem_ref.at[slot_idx])
 
@@ -510,7 +530,7 @@ def _topk_hist_dma_kernel(*refs, n_words: int, l: int,
         copy_tile(nxt_slot, nxt_t, nxt_i).start()
 
     copy_tile(slot, t, i).wait()
-    acc = _popcount_rows(buf_ref[slot], queries_ref[0], n_words)
+    acc = _popcount_lanes(buf_ref[slot], queries_ref[0], n_words)
     out_d, out_i = _hist_select(acc, i * block_n, l, n_valid, max_dist,
                                 block_n, act)
     out_d_ref[0, 0], out_i_ref[0, 0] = _pack_cand(out_d, out_i, pack,
@@ -536,18 +556,18 @@ def hamming_topk_hist_kernel(codes, queries, l: int, n_valid: int, *,
     ``hamming_topk_fused_kernel`` ("none" / "16" / "8"); selection always
     runs on the int32 VMEM tile.
 
+    codes: (G, W, n_pad / 128, 128) uint32, lane-dense (module docstring).
     dma=True streams code tiles through the manually double-buffered async
-    copy pipeline (the kernel then reads a (G, W, n_pad) transpose of
-    ``codes`` from HBM/ANY memory space); dma=False uses ordinary BlockSpec
-    streaming.  Both are exact.
+    copy pipeline (``codes`` stays in HBM/ANY memory space); dma=False uses
+    ordinary BlockSpec streaming.  Both are exact.
 
     active: optional (1, n_pad) int32 per-row activity flags shared by all
     G groups (0 = tombstone / pad -> sentinel before selection); traced, so
     serving can flip tombstones without recompiling.
     """
-    g, n_pad, w = codes.shape
+    g, w, r, _ = codes.shape
     b = queries.shape[1]
-    grid_n = n_pad // block_n
+    grid_n = r * LANE // block_n
     max_dist = 32 * w
     d_dtype, i_dtype, _ = cand_encoding(pack, w, block_n)
     out_shapes = [jax.ShapeDtypeStruct((g, grid_n, b, l), d_dtype),
@@ -558,10 +578,8 @@ def hamming_topk_hist_kernel(codes, queries, l: int, n_valid: int, *,
     ]
     act_spec = pl.BlockSpec((1, block_n), lambda t, i: (0, i))
     if not dma:
-        in_specs = [
-            pl.BlockSpec((1, block_n, w), lambda t, i: (t, i, 0)),
-            pl.BlockSpec((1, b, w), lambda t, i: (t, 0, 0)),
-        ]
+        in_specs = [_lane_spec(w, block_n),
+                    pl.BlockSpec((1, b, w), lambda t, i: (t, 0, 0))]
         operands = [codes, queries]
         if active is not None:
             in_specs.append(act_spec)
@@ -583,7 +601,7 @@ def hamming_topk_hist_kernel(codes, queries, l: int, n_valid: int, *,
         pl.BlockSpec(memory_space=pl.ANY),            # codes stay in HBM
         pl.BlockSpec((1, b, w), lambda t, i: (t, 0, 0)),
     ]
-    operands = [jnp.swapaxes(codes, 1, 2), queries]
+    operands = [codes, queries]
     if active is not None:
         in_specs.append(act_spec)
         operands.append(active)
@@ -597,7 +615,7 @@ def hamming_topk_hist_kernel(codes, queries, l: int, n_valid: int, *,
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=[
-            pltpu.VMEM((2, w, block_n), jnp.uint32),  # double buffer
+            pltpu.VMEM((2, w, block_n // LANE, LANE), jnp.uint32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         compiler_params=pltpu.CompilerParams(

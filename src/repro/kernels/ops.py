@@ -11,7 +11,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.search import env_cand_pack, env_fused_select
+from repro.core.search import (LANE_BLOCK, LANE_TILE, env_cand_pack,
+                               env_fused_select, to_lanes)
 from repro.kernels.bilinear_hash import (bilinear_hash_kernel,
                                          bilinear_hash_seeded_kernel)
 from repro.kernels.hamming import (DIST_SENTINEL, LANE, cand_encoding,
@@ -212,7 +213,9 @@ def hamming_topk_grouped(codes, queries, l: int, *, block_n: int = 4096,
     Returns (dists (G, B, l) int32, ids (G, B, l) int32) with ids local to
     the group's row space, sorted ascending by (distance, id) — bit-identical
     to per-group jax.lax.top_k(-dists).  When l > n the tail columns carry
-    (DIST_SENTINEL, -1).
+    (DIST_SENTINEL, -1).  The codes are laid out lane-dense
+    (``core.search.to_lanes``) inside the same program; a caller that keeps
+    them lane-dense calls ``hamming_topk_lanes``.
 
     select: block-local selection algorithm — ``"hist"`` (default;
     counting-sort select, O(block_n·B·log 32W) tile passes independent of
@@ -237,31 +240,85 @@ def hamming_topk_grouped(codes, queries, l: int, *, block_n: int = 4096,
     """
     select = env_fused_select(select)
     pack = env_cand_pack(pack)
-    return _topk_grouped_impl(codes, queries, active, l, block_n=block_n,
+    return _topk_grouped_rows(codes, queries, active, l, block_n=block_n,
                               interpret=_interpret_default(interpret),
                               select=select, dma=dma, pack=pack)
 
 
 @functools.partial(jax.jit, static_argnames=("l", "block_n", "interpret",
                                              "select", "dma", "pack"))
-def _topk_grouped_impl(codes, queries, active, l: int, *, block_n: int,
+def _topk_grouped_rows(codes, queries, active, l: int, *, block_n: int,
                        interpret: bool, select: str, dma: bool, pack: str):
-    g, n, w = codes.shape
+    """``hamming_topk_grouped``'s program: the (G, n, W) codes laid out
+    lane-dense, then the fused scan.  A block that does not cover the table
+    is cut to whole (8, 128) tiles."""
+    n = codes.shape[1]
+    bn = _block_rows(n, block_n, -(-queries.shape[1] // SUBLANE) * SUBLANE)
+    if LANE_TILE <= bn < n:
+        bn -= bn % LANE_TILE
+    return _topk_grouped_impl(to_lanes(codes, -(-n // bn) * bn), queries,
+                              active, l, n=n, block_n=bn,
+                              interpret=interpret, select=select, dma=dma,
+                              pack=pack)
+
+
+def lane_block(n_pad: int, b: int) -> int:
+    """Row block of a fused scan over a lane-dense table of n_pad rows
+    (``core.search.lane_rows``) at a b-query batch: the largest the VMEM
+    cap (``_TILE_ELEMS`` / b, at most the table's padding granule
+    ``LANE_BLOCK``) allows — the whole table where it fits one block, else
+    the largest power-of-two multiple of 1024 rows under the cap that
+    divides n_pad.  A scan's cost is mostly per block (the select
+    emits l slots a block, and the merge sorts them all): 2.5e7 codes, 10
+    queries on one v5e take 92.7 ms a scan at 4096 rows a block, 32.7 ms
+    at 16384."""
+    cap = max(LANE_TILE, min(LANE_BLOCK, _TILE_ELEMS // b))
+    if n_pad <= cap:
+        return n_pad
+    bn = LANE_TILE
+    while bn * 2 <= cap and n_pad % (bn * 2) == 0:
+        bn *= 2
+    return bn
+
+
+def hamming_topk_lanes(codes, queries, l: int, n: int, *,
+                       interpret: bool | None = None,
+                       select: str | None = None, active=None,
+                       pack: str | None = None):
+    """``hamming_topk_grouped`` over codes kept lane-dense: codes
+    (G, W, n_pad / 128, 128) with n_pad = ``core.search.lane_rows(n)``,
+    rows >= n padding.  The scan reads them where they lie: no pad and no
+    relayout of the table in the program."""
+    select = env_fused_select(select)
+    pack = env_cand_pack(pack)
+    b = -(-queries.shape[1] // SUBLANE) * SUBLANE
+    return _topk_grouped_impl(codes, queries, active, l, n=n,
+                              block_n=lane_block(codes.shape[2] * LANE, b),
+                              interpret=_interpret_default(interpret),
+                              select=select, dma=False, pack=pack)
+
+
+@functools.partial(jax.jit, static_argnames=("l", "n", "block_n",
+                                             "interpret", "select", "dma",
+                                             "pack"))
+def _topk_grouped_impl(codes, queries, active, l: int, *, n: int,
+                       block_n: int, interpret: bool, select: str, dma: bool,
+                       pack: str):
+    g, w, r, _ = codes.shape
     b = queries.shape[1]
     q = _pad_to(queries, 1, SUBLANE)
-    bn = _block_rows(n, block_n, q.shape[1])
-    padded = _pad_to(codes, 1, bn)
+    bn = block_n
     l_k = min(l, bn)    # a block holds bn rows; l_k = bn already emits all
     act = None
     if active is not None:
-        act = _pad_to(active.astype(jnp.int32)[None, :], 1, bn)
+        act = _pad_to(active.astype(jnp.int32)[None, :], 1, r * LANE)
     if select == "hist":
         cd, ci = hamming_topk_hist_kernel(
-            padded, q, l_k, n, active=act, block_n=bn, interpret=interpret,
+            codes, q, l_k, n, active=act, block_n=bn, interpret=interpret,
             dma=dma, pack=pack)
     else:
         cd, ci = hamming_topk_fused_kernel(
-            padded, q, l_k, n, active=act, block_n=bn, interpret=interpret,
+            codes, q, l_k, n, active=act, block_n=bn, interpret=interpret,
             pack=pack)
     grid_n = cd.shape[1]
     # widen the narrow block emission: the pack sentinel (the clamp of

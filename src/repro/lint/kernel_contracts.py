@@ -286,9 +286,11 @@ def default_registry() -> list:
         # (w=7 legal, w=8 illegal for pack="8"), the int16 id ceiling
         # (block_n 8192 fine, int16 ids hold block-local rows < 32768),
         # grouped launches, a live-rows mask, and a bigger-than-VMEM table.
+        # Codes are lane-dense (G, W, n_pad / 128, 128), so a block that
+        # does not cover the table is a multiple of 1024 rows.
         for pack in ("none", "16", "8"):
             for w in (1, 7, 8):
-                for block_n, g, b, l in ((256, 1, 8, 8), (2048, 4, 32, 128),
+                for block_n, g, b, l in ((1024, 1, 8, 8), (2048, 4, 32, 128),
                                          (8192, 2, 128, 512)):
                     for dma in dma_values:
                         for masked in (False, True):
@@ -297,8 +299,9 @@ def default_registry() -> list:
                             if dma_values != (False,):
                                 kw["dma"] = dma
                             n_pad = 2 * block_n
-                            args = [z((g, n_pad, w)), z((g, b, w)),
-                                    min(l, block_n), n_pad - 3]
+                            args = [z((g, w, n_pad // 128, 128)),
+                                    z((g, b, w)), min(l, block_n),
+                                    n_pad - 3]
                             if masked:
                                 kw["active"] = z((1, n_pad), jnp.int32)
                             yield Case(

@@ -76,18 +76,20 @@ def scan_trace_targets() -> dict:
 
     return {
         "ops._topk_grouped_impl": ops._topk_grouped_impl,
+        "ops._topk_grouped_rows": ops._topk_grouped_rows,
         "search.hamming_topk_grouped_hist": search.hamming_topk_grouped_hist,
         "search._grouped_topk_lax": search._grouped_topk_lax,
         "search.merge_topk_segments": search.merge_topk_segments,
-        "search.drop_tombstones_topk": search.drop_tombstones_topk,
         "search.margin_rerank_batch": search.margin_rerank_batch,
         "search.margin_rerank_segmented": search.margin_rerank_segmented,
         "search._sharded_fn": search._sharded_fn,
-        "search._grouped_sharded_fn": search._grouped_sharded_fn,
+        "search._sharded_scan_fn": search._sharded_scan_fn,
+        "search._sharded_rerank_fn": search._sharded_rerank_fn,
         "bq._bh_query_codes": bq._bh_query_codes,
         "bq._bh_db_codes": bq._bh_db_codes,
         "bq._seeded_query_codes": bq._seeded_query_codes,
         "bq.dedup_candidates": bq.dedup_candidates,
+        "bq.dedup_packed": bq.dedup_packed,
         "bq.mask_candidates": bq.mask_candidates,
         "ops.bilinear_hash_seeded_grouped": ops.bilinear_hash_seeded_grouped,
     }

@@ -22,9 +22,9 @@ Three layers, separated so the policy is testable without sleeps:
   the counters (queue depth, batch-size histogram, p50/p95/p99 request
   latency).
 - the inner ``HashQueryService`` — answers each flushed batch through
-  either backend (``mode="probe"`` or ``mode="scan"``, sharded scan via
-  ``mesh=``), which is what makes async results bit-identical to the
-  synchronous ``query_batch`` for the same request set.
+  either backend (``mode="probe"`` or ``mode="scan"``), which is what
+  makes async results bit-identical to the synchronous ``query_batch``
+  for the same request set.
 
 Requests that carry a ``mask`` (AL restricts answers to the unlabeled
 pool) are grouped by mask identity inside a flush: requests passing the
@@ -178,12 +178,11 @@ class AsyncHashQueryService:
     def __init__(self, index: MultiTableIndex, *, max_batch: int | None = None,
                  deadline_ms: float = 5.0, max_queue: int = 1024,
                  mode: str = "probe", cache_size: int = 1024,
-                 scan_l: int = 16, mesh=None, shard_axis: str = "data",
-                 bucket_batches: bool = True,
+                 scan_l: int = 16, bucket_batches: bool = True,
                  clock=time.monotonic, start: bool = True):
         self.service = HashQueryService(
             index, max_batch=max_batch, cache_size=cache_size, mode=mode,
-            scan_l=scan_l, mesh=mesh, shard_axis=shard_axis)
+            scan_l=scan_l)
         self.max_batch = self.service.max_batch
         self.deadline_s = float(deadline_ms) * 1e-3
         self.bucket_batches = bucket_batches

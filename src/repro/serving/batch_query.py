@@ -28,8 +28,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
+
 from repro.core.functions import BHHash, SeededBHHash, bilinear_signs
-from repro.core.search import margin_rerank_batch
+from repro.core.search import (LANE, lane_rows, margin_rerank_batch,
+                               to_lanes)
 from repro.utils.bits import flip_packed, pack_signs
 
 PAD_MULTIPLE = 128  # candidate-matrix padding quantum (bounds jit retraces)
@@ -91,7 +95,17 @@ def _bh_db_codes(u_stack, v_stack, x):
         u_stack, v_stack)
 
 
-def hash_queries_all(families, w, use_kernels: bool = False) -> jax.Array:
+@functools.lru_cache(maxsize=16)
+def _replicated_query_codes(mesh, k: int):
+    """``_seeded_query_codes`` run alike on every device of ``mesh``, for
+    queries replicated over it (a kernel is not partitioned for you)."""
+    return jax.jit(jax.shard_map(
+        lambda w, seeds: _seeded_query_codes(w, seeds, k), mesh=mesh,
+        in_specs=(P(), P()), out_specs=P(), check_vma=False))
+
+
+def hash_queries_all(families, w, use_kernels: bool = False,
+                     mesh=None) -> jax.Array:
     """Query-side codes for all tables: (L, B, W) uint32.
 
     use_kernels=True routes all-SeededBHHash families through the grouped
@@ -100,11 +114,15 @@ def hash_queries_all(families, w, use_kernels: bool = False) -> jax.Array:
     h(P_w) = -h(w) is the packed-bit complement of the database-style
     codes (sgn flips every bit: prod >= 0 pairs exactly with prod < 0
     under the sgn(0)=+1 convention), so the result is bit-identical to
-    the stacked jnp path.  Runs under the ``repro.hash`` host span.
+    the stacked jnp path.  ``mesh``: w is replicated over it, and so are
+    the codes.  Runs under the ``repro.hash`` host span.
     """
     with TraceAnnotation("repro.hash"):
         w = jnp.asarray(w, jnp.float32)
         if use_kernels and _seed_stackable(families):
+            if mesh is not None:
+                return _replicated_query_codes(mesh, families[0].k)(
+                    w, _seeds_of(families))
             return _seeded_query_codes(w, _seeds_of(families),
                                        families[0].k)
         if _stackable(families):
@@ -131,6 +149,56 @@ def hash_database_all(families, x, use_kernels: bool = False) -> jax.Array:
     return jnp.stack([f.hash_database(x) for f in families])
 
 
+def hash_database_sharded(families, x, mesh, axis: str,
+                          block: int = 1 << 20):
+    """Lane-dense database codes of features row-sharded over mesh axis
+    ``axis``, hashed on each shard from its own rows and left there:
+    (L, W, shards · lane_rows(n_local) / 128, 128) uint32, sharded along
+    the lane rows — the layout ``core.search.sharded_topk_lanes`` scans.
+
+    Each shard hashes its n_local rows ``block`` at a time, one launch a
+    block (a loop inside one program would let XLA cast the whole shard to
+    the product's operand type at once), with the stacked bilinear product
+    of ``_bh_db_codes`` — bit-identical to the seeded kernel, which
+    regenerates the same projections — because an XLA product reads the
+    rows in whatever layout the device keeps them, where a kernel's operand
+    would first be copied, whole, into rows-major order."""
+    if not _stackable(families):
+        raise ValueError("row-sharded features need BH-family tables")
+    u = jnp.stack([f.u for f in families])
+    v = jnp.stack([f.v for f in families])
+    n_local = x.shape[0] // mesh.shape[axis]
+    blk = min(block, n_local // LANE * LANE) or n_local
+    shards = mesh.shape[axis]
+    lanes = jax.jit(lambda: jnp.zeros(
+        (u.shape[0], -(-u.shape[2] // 32),
+         shards * lane_rows(n_local) // LANE, LANE), jnp.uint32),
+        out_shardings=NamedSharding(mesh, P(None, None, axis, None)))()
+    step = sharded_hash_step(u, v, mesh, axis, blk)
+    for start in range(0, n_local - blk + 1, blk):
+        lanes = step(x, lanes, start)
+    if n_local % blk:
+        tail = sharded_hash_step(u, v, mesh, axis, n_local % blk)
+        lanes = tail(x, lanes, n_local - n_local % blk)
+    return lanes
+
+
+def sharded_hash_step(u, v, mesh, axis: str, blk: int):
+    """One block of ``hash_database_sharded``: each shard hashes its rows
+    [start, start + blk) and writes their lane-dense codes into ``lanes``
+    (donated) at lane row start / 128; start is a multiple of 128."""
+    def local(xs, lanes, start):
+        c = _bh_db_codes(u, v, jax.lax.dynamic_slice_in_dim(xs, start, blk))
+        return jax.lax.dynamic_update_slice_in_dim(
+            lanes, to_lanes(c, -(-blk // LANE) * LANE), start // LANE, 2)
+
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P(axis, None), P(None, None, axis, None),
+                                    P()),
+        out_specs=P(None, None, axis, None), check_vma=False),
+        donate_argnums=1)
+
+
 @jax.jit
 def dedup_candidates(idx, live_rows=None, rows=None):
     """Device-side union/dedup of a scan's per-table top-l, one program.
@@ -153,6 +221,19 @@ def dedup_candidates(idx, live_rows=None, rows=None):
     else:
         grows = live_rows[jnp.clip(flat, 0, live_rows.shape[0] - 1)]
     return grows, uniq, (idx >= 0).sum(axis=(1, 2))
+
+
+@jax.jit
+def dedup_packed(idx, live_rows=None, rows=None):
+    """``dedup_candidates`` with what the host reads packed into one
+    (B + 1, L·l) int32 array: per query its deduplicated candidate rows
+    (-1 where a slot repeats or is empty), and in the last row the (L,)
+    per-table hits.  Returns (grows (B, L·l) for the re-rank, packed)."""
+    grows, uniq, hits = dedup_candidates(idx, live_rows, rows)
+    last = jnp.zeros((1, grows.shape[1]), jnp.int32)
+    last = last.at[0, :hits.shape[0]].set(hits.astype(jnp.int32))
+    cand = jnp.where(uniq, grows, -1).astype(jnp.int32)
+    return grows, jnp.concatenate([cand, last])
 
 
 @jax.jit

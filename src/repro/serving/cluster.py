@@ -1,7 +1,7 @@
 """Replicated-shard serving: R-way replicated row shards behind one router.
 
 ``ShardReplicaRouter`` is the robustness tier between the single-process
-sharded scan (core.search.hamming_topk_grouped_sharded) and a true
+sharded scan (an index fitted on row-sharded features) and a true
 multi-host deployment: the row space is split round-robin over S shards,
 each shard is served by R replica ``LSMMultiTableIndex`` instances built
 from the SAME ``IndexConfig`` (same seed ⇒ identical hash families
@@ -490,15 +490,14 @@ class ShardReplicaRouter:
 
     # -- queries -------------------------------------------------------------
 
-    def _scan_covered_shard(self, s: int, w: np.ndarray, l: int, mesh,
-                            shard_axis: str, gids: np.ndarray):
+    def _scan_covered_shard(self, s: int, w: np.ndarray, l: int,
+                            gids: np.ndarray):
         """Phase-1 ladder for one shard: per-table (dist, local-id) top-l
         from a healthy replica, mapped to GLOBAL ids.  Runs on
         _shard_pool, so shards scan (and fail over) concurrently."""
         r, (d, ids) = self._shard_ladder(
             s, "scan",
-            lambda rep: rep.scan_table_topk(w, l, mesh=mesh,
-                                            shard_axis=shard_axis))
+            lambda rep: rep.scan_table_topk(w, l))
         known = (ids >= 0) & (ids < gids.size)
         g = np.where(known, gids[np.clip(ids, 0, gids.size - 1)], -1)
         # rows newer than this query's snapshot (concurrent insert racing
@@ -506,8 +505,7 @@ class ShardReplicaRouter:
         d = np.where(known | (ids < 0), d, DIST_SENTINEL).astype(np.int32)
         return r, d, g
 
-    def query_scan_batch(self, w, l: int = 16, topk: int = 1, mask=None,
-                         mesh=None, shard_axis: str = "data"
+    def query_scan_batch(self, w, l: int = 16, topk: int = 1, mask=None
                          ) -> BatchQueryResult:
         """Cluster-wide scan answer (see module docstring for the
         protocol).  Never raises on replica failure — lost shards shrink
@@ -538,8 +536,7 @@ class ShardReplicaRouter:
         # phase 1: parallel per-shard scans with failover ladders
         want = [s for s in range(self.shards) if live[s] > 0]
         futs = {s: self._shard_pool.submit(
-                    self._scan_covered_shard, s, w, l, mesh, shard_axis,
-                    gids_snap[s])
+                    self._scan_covered_shard, s, w, l, gids_snap[s])
                 for s in want}
         scans: dict[int, tuple] = {}
         served: dict[int, int] = {}

@@ -36,11 +36,7 @@ liveness mask rides into the scan itself (the ``active=`` operand of
 shape-padding rows are set to the distance sentinel before selection, so
 the scan is exactly ``l`` deep and the mask is a TRACED operand — inserts,
 deletes and compaction swaps never change a jit trace key (device shapes
-stay pinned to sticky power-of-two pad buckets).  The sharded path instead
-overscans ``l + slack`` deep (slack >= tombstone count, quantized) and
-filters with ``core.search.drop_tombstones_topk`` — the slack contract:
-at most ``slack`` of the scanned slots can be dead, so the surviving
-top-l is exactly the top-l of the live rows.
+stay pinned to sticky power-of-two pad buckets).
 
 Incremental compaction: past the delta/dead-fraction thresholds the index
 freezes the current delta and folds base + frozen delta into a new base a
@@ -61,16 +57,12 @@ from __future__ import annotations
 import threading
 import time
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
-from jax.sharding import PartitionSpec as P
 
 from repro.core.indexer import IndexConfig
-from repro.core.search import (DIST_SENTINEL, _pad_topk, drop_tombstones_topk,
-                               hamming_topk_grouped,
-                               hamming_topk_grouped_sharded, margin_batch,
+from repro.core.search import (DIST_SENTINEL, hamming_topk_grouped,
+                               margin_batch,
                                margin_batch_segmented, margin_rerank_batch,
                                margin_rerank_segmented, merge_topk_segments)
 from repro.core.tables import SingleHashTable
@@ -85,14 +77,6 @@ def _pow2_at_least(v: int, floor: int = 1) -> int:
     while p < v:
         p *= 2
     return p
-
-
-def _to_l(d, i, l: int):
-    """Truncate/pad a sorted candidate list to exactly l slots."""
-    d, i = d[..., :l], i[..., :l]
-    if d.shape[-1] < l:
-        d, i = _pad_topk(d, i, l)
-    return d, i
 
 
 class _Compaction:
@@ -726,27 +710,16 @@ class LSMMultiTableIndex(MultiTableIndex):
 
     # -- device segment states -----------------------------------------------
 
-    def _base_codes_state(self, mesh, axis):
+    def _base_codes_state(self):
         # lock held by caller
-        layout = None if mesh is None else (mesh, axis)
-        key = (self._base_version, layout)
+        key = self._base_version
         if self._base_codes_key != key:
             bl = self._base_len
-            if mesh is None:
-                bcap = self._bcap
-                stacked = np.zeros(
-                    (self.num_tables, bcap, self._codes_buf.shape[2]),
-                    np.uint32)
-                stacked[:, :bl] = self._codes_buf[:, :bl]
-                self._base_codes_dev = jnp.asarray(stacked)
-            else:
-                stacked = np.ascontiguousarray(self._codes_buf[:, :bl])
-                shards = mesh.shape[axis]
-                pad = (-bl) % shards
-                if pad:
-                    stacked = np.pad(stacked, ((0, 0), (0, pad), (0, 0)))
-                self._base_codes_dev = jax.device_put(
-                    stacked, NamedSharding(mesh, P(None, axis, None)))
+            stacked = np.zeros(
+                (self.num_tables, self._bcap, self._codes_buf.shape[2]),
+                np.uint32)
+            stacked[:, :bl] = self._codes_buf[:, :bl]
+            self._base_codes_dev = jnp.asarray(stacked)
             self._base_codes_key = key
             self.scan_state_rebuilds += 1
             self.device_uploads += 1
@@ -851,47 +824,29 @@ class LSMMultiTableIndex(MultiTableIndex):
         self._maybe_compact()
         return res
 
-    def _scan_segment(self, codes_dev, qcodes, l: int, seg_len: int,
-                      cap: int, dead: int, active_dev, fused: bool,
-                      select, pack, mesh, shard_axis):
+    def _scan_segment(self, codes_dev, qcodes, l: int, active_dev,
+                      fused: bool, select, pack):
         """Scan one segment and return its top-l LIVE candidates,
-        (G, B, l), lex-sorted, local row ids.  Single-device: exactly l
-        deep with the liveness mask applied inside selection; sharded:
-        l(+slack) deep with post-filtering."""
-        if mesh is not None:
-            # shard padding is masked inside the sharded scan (n_valid);
-            # tombstones still need the overscan-and-filter slack rule here
-            depth = (l if not dead
-                     else min(_pow2_at_least(l + dead), cap))
-            d, i = hamming_topk_grouped_sharded(
-                codes_dev, qcodes, depth, mesh,
-                axis=shard_axis, use_kernel=fused, n_valid=seg_len,
-                select=select, pack=pack)
-            if dead:
-                return drop_tombstones_topk(d, i, active_dev, l)
-            return _to_l(d, i, l)
-        # single-device path: tombstones AND pad rows are masked to the
-        # sentinel at distance level inside selection (active_dev is False
-        # for both), so the scan is exactly l deep and already filtered —
-        # one trace per (B, cap) pad bucket, immune to insert/delete/
-        # compaction churn (the mask is a traced operand, not a jit key)
+        (G, B, l), lex-sorted, local row ids.  Tombstones AND pad rows are
+        masked to the sentinel at distance level inside selection
+        (active_dev is False for both), so the scan is exactly l deep and
+        already filtered — one trace per (B, cap) pad bucket, immune to
+        insert/delete/compaction churn (the mask is a traced operand, not a
+        jit key)."""
         if fused:
             from repro.kernels import ops
-            d, i = ops.hamming_topk_grouped(codes_dev, qcodes, l,
+            return ops.hamming_topk_grouped(codes_dev, qcodes, l,
                                             select=select,
                                             active=active_dev, pack=pack)
-        else:
-            d, i = hamming_topk_grouped(codes_dev, qcodes, l,
-                                        select=select, active=active_dev)
-        return d, i
+        return hamming_topk_grouped(codes_dev, qcodes, l, select=select,
+                                    active=active_dev)
 
-    def query_scan_batch(self, w, l: int = 16, topk: int = 1, mask=None,
-                         mesh=None, shard_axis: str = "data"
+    def query_scan_batch(self, w, l: int = 16, topk: int = 1, mask=None
                          ) -> BatchQueryResult:
         """Two-segment fused scan (see parent for the l/topk contract).
 
         The base segment scans exactly like the monolithic index (fused
-        kernel / jnp / sharded per config and mesh); the delta scans as
+        kernel / jnp per config); the delta scans as
         plain jnp until it exceeds ``config.lsm_delta_fused_rows``; the two
         candidate lists merge through core.search.merge_topk_segments.
         All geometry and device handles are snapshotted under the lock, so
@@ -919,19 +874,12 @@ class LSMMultiTableIndex(MultiTableIndex):
                     np.zeros(self.num_tables, dtype=np.int64),
                     ids_topk=ids_pad if topk > 1 else None,
                     margins_topk=m_pad if topk > 1 else None)
-            base_dead = split - int(active_view[:split].sum())
             delta_len = rows - split
-            delta_dead = (delta_len
-                          - int(active_view[split:rows].sum()))
-            base_codes = (self._base_codes_state(mesh, shard_axis)
-                          if split else None)
+            base_codes = self._base_codes_state() if split else None
             base_active = (self._base_active_state()
                            if split else None)
             base_x = self._base_x_state()
             delta = self._delta_state() if delta_len else None
-            bcap = (self._bcap if mesh is None
-                    else _pow2_at_least(split, _MIN_CAP))
-            dcap = _pow2_at_least(delta_len, self._delta_floor)
             fams = self.families    # snapshot WITH the device handles: a
             # refresh swap between this block and the hash below must not
             # pair new-generation qcodes with old-generation codes
@@ -943,15 +891,14 @@ class LSMMultiTableIndex(MultiTableIndex):
         d_m = i_m = None
         if base_codes is not None:
             d_b, i_b = self._scan_segment(
-                base_codes, qcodes, l, split, bcap, base_dead, base_active,
-                cfg.use_kernels, select, pack, mesh, shard_axis)
+                base_codes, qcodes, l, base_active, cfg.use_kernels, select,
+                pack)
             d_m, i_m = d_b, i_b
         if delta is not None:
             delta_codes, delta_x, delta_active = delta
             fused = cfg.use_kernels and delta_len >= cfg.lsm_delta_fused_rows
             d_d, i_d = self._scan_segment(
-                delta_codes, qcodes, l, delta_len, dcap, delta_dead,
-                delta_active, fused, select, pack, None, shard_axis)
+                delta_codes, qcodes, l, delta_active, fused, select, pack)
             # delta-local ids -> global rows (sentinels stay -1)
             i_d = jnp.where(i_d < 0, jnp.int32(-1),
                             i_d + jnp.int32(split))
@@ -992,8 +939,7 @@ class LSMMultiTableIndex(MultiTableIndex):
 
     # -- replicated-shard serving hooks (serving.cluster) --------------------
 
-    def scan_table_topk(self, w, l: int = 16, mesh=None,
-                        shard_axis: str = "data"
+    def scan_table_topk(self, w, l: int = 16
                         ) -> tuple[np.ndarray, np.ndarray]:
         """Two-segment override of the parent hook: scan base + delta and
         merge through merge_topk_segments BEFORE translating to stable ids,
@@ -1015,16 +961,10 @@ class LSMMultiTableIndex(MultiTableIndex):
                 return (np.full((self.num_tables, b, l), DIST_SENTINEL,
                                 np.int32),
                         np.full((self.num_tables, b, l), -1, np.int64))
-            base_dead = split - int(active_view[:split].sum())
             delta_len = rows - split
-            delta_dead = delta_len - int(active_view[split:rows].sum())
-            base_codes = (self._base_codes_state(mesh, shard_axis)
-                          if split else None)
+            base_codes = self._base_codes_state() if split else None
             base_active = self._base_active_state() if split else None
             delta = self._delta_state() if delta_len else None
-            bcap = (self._bcap if mesh is None
-                    else _pow2_at_least(split, _MIN_CAP))
-            dcap = _pow2_at_least(delta_len, self._delta_floor)
             fams = self.families
         qcodes = bq.hash_queries_all(fams, w, use_kernels=cfg.use_kernels)
         select = cfg.fused_select
@@ -1032,14 +972,13 @@ class LSMMultiTableIndex(MultiTableIndex):
         d_m = i_m = None
         if base_codes is not None:
             d_m, i_m = self._scan_segment(
-                base_codes, qcodes, l, split, bcap, base_dead, base_active,
-                cfg.use_kernels, select, pack, mesh, shard_axis)
+                base_codes, qcodes, l, base_active, cfg.use_kernels, select,
+                pack)
         if delta is not None:
             delta_codes, _, delta_active = delta
             fused = cfg.use_kernels and delta_len >= cfg.lsm_delta_fused_rows
             d_d, i_d = self._scan_segment(
-                delta_codes, qcodes, l, delta_len, dcap, delta_dead,
-                delta_active, fused, select, pack, None, shard_axis)
+                delta_codes, qcodes, l, delta_active, fused, select, pack)
             i_d = jnp.where(i_d < 0, jnp.int32(-1), i_d + jnp.int32(split))
             if d_m is None:
                 d_m, i_m = d_d, i_d

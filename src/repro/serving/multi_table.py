@@ -7,6 +7,17 @@ so a MultiTableIndex with L=1 reproduces a single-table index built from
 ``fold_in(key, 0)`` exactly, and the candidate set grows monotonically with
 L for a fixed seed — more tables can only add recall.
 
+Features on one device are kept on the host too (``x_np``), hashed into
+host bucket tables, and uploaded once for the re-rank.  Features that arrive
+row-sharded over a mesh axis (a ``NamedSharding`` whose spec shards rows)
+stay where they are: the index reads its mesh and axis from ``x.sharding``,
+hashes each shard's rows on its own chip, keeps the codes there, scans them
+shard by shard and re-ranks each candidate on the chip that owns its row.
+Such an index answers scan queries and takes deletes: it builds no host
+tables, a delete flags the row dead on its chip, and ``compact`` closes each
+shard's live rows up on that shard.  Insert and the probe path need the
+features on the host.
+
 Ids are stable across mutations: ``insert`` assigns fresh ids (never
 renumbers), ``delete`` tombstones rows out of every table, and ``compact``
 (auto-triggered past ``IndexConfig.compact_threshold`` dead fraction, or
@@ -19,6 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
@@ -30,9 +42,10 @@ from jax.sharding import PartitionSpec as P
 from repro.core import functions as F
 from repro.core import learning as L
 from repro.core.indexer import IndexConfig, QueryResult
-from repro.core.search import (DIST_SENTINEL, hamming_topk_grouped,
-                               hamming_topk_grouped_sharded, margin_batch,
-                               margin_rerank_batch)
+from repro.core.search import (DIST_SENTINEL, from_lanes, gather_rows,
+                               hamming_topk_grouped, lane_rows, margin_batch,
+                               margin_rerank_batch, rows_minor,
+                               sharded_rerank, sharded_topk_lanes, to_lanes)
 from repro.core.tables import SingleHashTable, keys_of
 from repro.serving import batch_query as bq
 
@@ -56,11 +69,50 @@ class BatchQueryResult:
     degraded: bool = False
 
 
-def _fetch(x) -> np.ndarray:
+def _fetch(x):
     """One blocking device-to-host read, under its own ``repro.fetch``
-    span."""
+    span: an array, or a tuple of arrays whose copies start together."""
     with TraceAnnotation("repro.fetch"):
-        return np.asarray(x)
+        return jax.device_get(x)
+
+
+def _row_mesh(x):
+    """(mesh, axis) of features row-sharded over one mesh axis, or (None,
+    None) for features on one device (or replicated)."""
+    sh = getattr(x, "sharding", None)
+    if not isinstance(sh, NamedSharding) or len(sh.device_set) < 2:
+        return None, None
+    spec = tuple(sh.spec) + (None, None)
+    axis = spec[0]
+    if isinstance(axis, tuple):
+        axis = axis[0] if len(axis) == 1 else axis
+    if spec[1] is not None or isinstance(axis, tuple):
+        raise ValueError(f"fit: features must be on one device or "
+                         f"row-sharded over one mesh axis, got {sh.spec}")
+    if axis is None:
+        return None, None
+    return sh.mesh, axis
+
+
+_lanes = jax.jit(to_lanes, static_argnums=1)
+_rows = jax.jit(from_lanes, static_argnums=1)
+
+
+@lru_cache(maxsize=16)
+def _compact_fn(mesh, axis: str, n_local: int, keep: int, minor: bool):
+    """One program for ``MultiTableIndex._compact_shards``: each shard
+    gathers the rows ``src`` names (keep of them, shard-local) from its own
+    features and lane-dense codes."""
+    def local(x, codes, src):
+        codes = from_lanes(codes, n_local)[:, src]
+        return (gather_rows(x, src, minor),
+                to_lanes(codes, lane_rows(keep)))
+
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(axis, None), P(None, None, axis, None), P(axis)),
+        out_specs=(P(axis, None), P(None, None, axis, None)),
+        check_vma=False))
 
 
 class MultiTableIndex:
@@ -103,8 +155,18 @@ class MultiTableIndex:
         self._codes_dev = None        # (L, n_live[_pad], W) stacked live codes
         self._live_rows: np.ndarray | None = None
         self._live_rows_dev = None
-        self._scan_key = None         # (mesh, axis) the device codes are laid
-                                      # out for; None = single device
+        # row-sharded features (fit reads them from x.sharding); None =
+        # one device
+        self.mesh = None
+        self.axis = None
+        self._n_local = 0             # rows a shard holds
+        self._x_minor = False         # rows on the device's minor axis
+        self._n_live = 0              # live rows the scan state holds
+        self._pads = 0                # dead rows that hold no id: a shard's
+                                      # block past its live rows after a
+                                      # row-sharded compact
+        self._active_dev = None       # row-sharded liveness flags; None =
+                                      # every row live
 
     # -- build ---------------------------------------------------------------
 
@@ -136,21 +198,38 @@ class MultiTableIndex:
     def fit(self, x, learn_key=None) -> "MultiTableIndex":
         t0 = time.perf_counter()
         x = jnp.asarray(x, jnp.float32)
+        mesh, axis = _row_mesh(x)
         self.families = [self._make_family(self.table_key(t, learn_key), x)
                          for t in range(self.num_tables)]
-        codes_all = np.asarray(bq.hash_database_all(
-            self.families, x, use_kernels=self.config.use_kernels))
-        self.codes = [codes_all[t] for t in range(self.num_tables)]
-        self.tables = [SingleHashTable(c, self.config.bits)
-                       for c in self.codes]
-        self.x_np = np.asarray(x)
-        n = self.x_np.shape[0]
+        self.mesh = None
+        self._invalidate()
+        self.mesh, self.axis = mesh, axis
+        n = x.shape[0]
+        if self.mesh is None:
+            codes_all = np.asarray(bq.hash_database_all(
+                self.families, x, use_kernels=self.config.use_kernels))
+            self.codes = [codes_all[t] for t in range(self.num_tables)]
+            self.tables = [SingleHashTable(c, self.config.bits)
+                           for c in self.codes]
+            self.x_np = np.asarray(x)
+            self._n_local = n
+        else:
+            self._n_local = n // self.mesh.shape[self.axis]
+            self.codes, self.tables, self.x_np = [], [], None
+            self._x_dev, self._x_minor = x, rows_minor(x)
+            self._codes_dev = bq.hash_database_sharded(
+                self.families, x, self.mesh, self.axis)
+            self.device_uploads += 1
+        self._n_live = n
+        self._pads = 0
         self.active = np.ones(n, dtype=bool)
-        self.ids_np = np.arange(n, dtype=np.int64)
-        self._row_of = np.arange(n, dtype=np.int64)
+        if self.mesh is None:
+            self.ids_np = np.arange(n, dtype=np.int64)
+            self._row_of = np.arange(n, dtype=np.int64)
+        else:           # stable ids == rows until the first compaction
+            self.ids_np = self._row_of = None
         self._next_id = n
         self.compactions = 0
-        self._invalidate()
         self.version += 1
         self.fit_s = time.perf_counter() - t0
         return self
@@ -158,19 +237,31 @@ class MultiTableIndex:
     def _invalidate(self, keep_x: bool = False) -> None:
         """Drop the device-resident caches derived from rows/codes.
         keep_x: the feature rows are unchanged (tombstone-only delete) —
-        don't force a full (rows, d) re-upload on the next re-rank."""
+        don't force a full (rows, d) re-upload on the next re-rank.
+        Row-sharded features and their codes are the index itself and stay;
+        only their liveness flags go."""
+        self._active_dev = None
+        if self.mesh is not None:
+            return
         if not keep_x:
             self._x_dev = None
         self._codes_dev = None
         self._live_rows = None
         self._live_rows_dev = None
-        self._scan_key = None
 
     def _require_fit(self, op: str) -> None:
-        if self.x_np is None:
+        if self.active is None:
             raise RuntimeError(
                 f"MultiTableIndex.{op} before fit(): build the index with "
                 f"fit(x) before mutating or querying it")
+
+    def _require_host_rows(self, op: str) -> None:
+        self._require_fit(op)
+        if self.mesh is not None:
+            raise NotImplementedError(
+                f"MultiTableIndex.{op}: the features are row-sharded over "
+                f"mesh axis {self.axis!r}; this index answers scan queries "
+                f"and takes deletes, and {op} needs them on the host")
 
     @property
     def n(self) -> int:
@@ -178,9 +269,15 @@ class MultiTableIndex:
         return int(self.active.sum())
 
     @property
+    def _dead(self) -> int:
+        """Tombstoned rows: rows that hold an id but are no longer live."""
+        return self.active.size - self._pads - int(self.active.sum())
+
+    @property
     def x(self):
         if self._x_dev is None:
             self._x_dev = jnp.asarray(self.x_np)
+            self._x_minor = rows_minor(self._x_dev)
             self.device_uploads += 1
         return self._x_dev
 
@@ -190,6 +287,8 @@ class MultiTableIndex:
         """Internal row numbers -> stable external ids (-1 passes through).
         Identity until the first compaction."""
         rows = np.asarray(rows, dtype=np.int64)
+        if self.ids_np is None:
+            return np.where(rows >= 0, rows, -1)
         out = np.full(rows.shape, -1, dtype=np.int64)
         m = rows >= 0
         out[m] = self.ids_np[rows[m]]
@@ -208,10 +307,13 @@ class MultiTableIndex:
         """
         self._require_fit("ids_to_rows")
         ids = np.asarray(ids, dtype=np.int64)
-        n_ids = self._row_of.shape[0]
+        n_ids = self._next_id if self._row_of is None else (
+            self._row_of.shape[0])
         if ids.size and (ids.min() < 0 or ids.max() >= n_ids):
             raise KeyError(f"unknown ids (never assigned): "
                            f"{ids[(ids < 0) | (ids >= n_ids)][:8]}")
+        if self._row_of is None:
+            return ids.copy()
         rows = self._row_of[ids]
         if (rows < 0).any():
             raise KeyError(f"ids compacted away: {ids[rows < 0][:8]}")
@@ -222,13 +324,14 @@ class MultiTableIndex:
         first compaction, where stable ids == rows)."""
         if mask is None:
             return None
-        return np.asarray(mask, dtype=bool)[self.ids_np]
+        mask = np.asarray(mask, dtype=bool)
+        return mask if self.ids_np is None else mask[self.ids_np]
 
     # -- dynamic updates -----------------------------------------------------
 
     def insert(self, x_new) -> np.ndarray:
         """Append rows to every table; returns the assigned stable ids."""
-        self._require_fit("insert")
+        self._require_host_rows("insert")
         x_new = np.atleast_2d(np.asarray(x_new, np.float32))
         if x_new.shape[0] == 0:
             return np.empty((0,), dtype=np.int64)
@@ -267,14 +370,16 @@ class MultiTableIndex:
         rows = self.ids_to_rows(ids)
         if not self.active[rows].all():
             raise KeyError("delete of already-deleted or unknown id")
-        for t in range(self.num_tables):
+        for t in range(len(self.tables)):
             self.tables[t].delete(rows)
         self.active[rows] = False
+        self._n_live -= rows.size
         self._invalidate(keep_x=True)
+        self._put_active()
         self.version += 1
         thresh = self.config.compact_threshold
-        dead = self.active.size - int(self.active.sum())
-        if thresh is not None and dead > thresh * self.active.size:
+        held = self.active.size - self._pads
+        if thresh is not None and self._dead > thresh * held:
             self.compact()
 
     def compact(self) -> np.ndarray:
@@ -285,8 +390,10 @@ class MultiTableIndex:
         surviving stable ids; no-op (no version bump) when nothing is dead.
         """
         self._require_fit("compact")
-        if self.active.all():
-            return self.ids_np.copy()
+        if not self._dead:
+            return self.rows_to_ids(np.flatnonzero(self.active))
+        if self.mesh is not None:
+            return self._compact_shards()
         live = np.flatnonzero(self.active)
         self.codes = [c[live] for c in self.codes]
         self.x_np = self.x_np[live]
@@ -302,6 +409,54 @@ class MultiTableIndex:
         self.compaction_steps += 1   # stop-the-world rebuild = one big step
         return self.ids_np.copy()
 
+    def _compact_shards(self) -> np.ndarray:
+        """``compact`` of row-sharded features: each shard closes its live
+        rows up, in order, at the front of its block on its own chip, so no
+        row crosses chips.  Every block keeps the fullest shard's live
+        count; a shard with fewer pads its block with dead rows that hold
+        no id.  Row order stays stable-id order, so ties still resolve to
+        the lowest id."""
+        shards, n_local = self.mesh.shape[self.axis], self._n_local
+        live = self.active.reshape(shards, n_local)
+        keep = max(int(live.sum(axis=1).max()), 1)
+        src = np.zeros((shards, keep), np.int32)
+        act = np.zeros((shards, keep), bool)
+        for s in range(shards):
+            rows = np.flatnonzero(live[s])
+            src[s, :rows.size] = rows
+            act[s, :rows.size] = True
+        ids = self.rows_to_ids(
+            (src + np.arange(shards)[:, None] * n_local)[act])
+        src_dev = jax.device_put(src.reshape(-1),
+                                 NamedSharding(self.mesh, P(self.axis)))
+        self._x_dev, self._codes_dev = _compact_fn(
+            self.mesh, self.axis, n_local, keep, self._x_minor)(
+                self._x_dev, self._codes_dev, src_dev)
+        self._x_minor = rows_minor(self._x_dev)
+        self._n_local = keep
+        self.active = act.reshape(-1)
+        self._pads = self.active.size - ids.size
+        self.ids_np = np.full(self.active.size, -1, dtype=np.int64)
+        self.ids_np[self.active] = ids
+        self._row_of = np.full(self._next_id, -1, dtype=np.int64)
+        self._row_of[ids] = np.flatnonzero(self.active)
+        self._put_active()
+        self.version += 1
+        self.compactions += 1
+        self.compaction_steps += 1
+        return ids.copy()
+
+    def _put_active(self) -> None:
+        """Row-sharded features: their liveness flags, sharded like the
+        rows, for the scan to rank dead rows out (None while every row is
+        live, so an index that never deletes scans without them)."""
+        if self.mesh is None or self.active.all():
+            self._active_dev = None
+            return
+        self._active_dev = jax.device_put(
+            self.active, NamedSharding(self.mesh, P(self.axis)))
+        self.device_uploads += 1
+
     # -- lookup / query ------------------------------------------------------
 
     def lookup_batch(self, w, qcodes: np.ndarray | None = None
@@ -313,7 +468,7 @@ class MultiTableIndex:
         Returns (per-query unioned candidate lists IN ROW SPACE — callers
         must translate with ``rows_to_ids`` before reporting, as the
         service does — and per-table hit counts)."""
-        self._require_fit("lookup_batch")
+        self._require_host_rows("lookup_batch")
         cfg = self.config
         w = np.atleast_2d(np.asarray(w, np.float32))
         if qcodes is None:
@@ -363,55 +518,46 @@ class MultiTableIndex:
         return QueryResult(int(res.ids[0]), float(res.margins[0]),
                            res.candidates[0], bool(res.nonempty[0]))
 
-    def _scan_state(self, mesh=None, axis: str = "data"):
-        """Device-resident stacked live codes for the fused scan: one
-        (L, n_live, W) array (tombstones compacted out, so deleted rows can
-        never crowd live answers out of the top-l slots) plus the
-        live-row map, rebuilt only when the index mutates or the layout
-        target changes.
-
-        With ``mesh``, the stacked codes are laid out row-sharded over the
-        mesh axis (padded host-side to the shard count so device_put never
-        reshards) — the layout hamming_topk_grouped_sharded scans with one
-        local launch per shard.
-        """
-        key = None if mesh is None else (mesh, axis)
-        if self._codes_dev is None or self._scan_key != key:
+    def _scan_state(self):
+        """Device-resident stacked live codes for the fused scan, lane-dense
+        (``core.search.to_lanes``): one (L, W, lane_rows(n_live) / 128, 128)
+        array (tombstones compacted out, so deleted rows can never crowd
+        live answers out of the top-l slots) plus the live-row map, rebuilt
+        only when the index mutates.  The scan program then reads them as
+        they lie.  Row-sharded features: the codes ``fit`` hashed on each
+        shard, dead rows among them (``_active_dev`` flags them)."""
+        if self._codes_dev is None:
             self.scan_state_rebuilds += 1
             self.device_uploads += 1
             self._live_rows = np.flatnonzero(self.active)
+            self._n_live = self._live_rows.size
             stacked = np.stack([c[self._live_rows] for c in self.codes])
-            if mesh is None:
-                self._codes_dev = jnp.asarray(stacked)
-            else:
-                shards = mesh.shape[axis]
-                pad = (-stacked.shape[1]) % shards
-                if pad:
-                    stacked = np.pad(stacked, ((0, 0), (0, pad), (0, 0)))
-                self._codes_dev = jax.device_put(
-                    stacked, NamedSharding(mesh, P(None, axis, None)))
+            self._codes_dev = _lanes(jnp.asarray(stacked),
+                                     lane_rows(self._n_live))
             self._live_rows_dev = jnp.asarray(self._live_rows)
-            self._scan_key = key
         return self._codes_dev, self._live_rows_dev
 
-    def query_scan_batch(self, w, l: int = 16, topk: int = 1, mask=None,
-                         mesh=None, shard_axis: str = "data"
+    def _put(self, a):
+        """Host array -> device: replicated over the mesh of row-sharded
+        features, else on the default device."""
+        if self.mesh is None:
+            return jnp.asarray(a)
+        return jax.device_put(a, NamedSharding(self.mesh, P()))
+
+    def query_scan_batch(self, w, l: int = 16, topk: int = 1, mask=None
                          ) -> BatchQueryResult:
         """Device-side batched scan: ONE fused Hamming kernel launch for all
-        L tables and B queries, then union/dedup and exact margin re-rank —
-        all on device.
+        L tables and B queries, then union/dedup and exact margin re-rank.
 
-        With ``mesh``, the stacked live codes are row-sharded over
-        ``shard_axis`` and the scan runs through
-        core.search.hamming_topk_grouped_sharded — one local launch per
-        shard, O(L·B·l·shards) interconnect bytes for the candidate merge,
-        answers bit-identical to the single-device scan.  Reuse the same
-        mesh object across calls: the sharded layout is cached per
-        (mesh, axis) and rebuilt when it changes.
+        Over row-sharded features, the scan runs one local launch per shard
+        (core.search.sharded_topk_lanes: O(L·B·l·shards) interconnect bytes
+        for the candidate merge) and each shard re-ranks the candidates it
+        owns (core.search.sharded_rerank); answers are bit-identical to the
+        one-device index over the same rows.
 
-        The L tables' live codes are stacked as a single (L, n_live, W)
-        device array and L is folded into the query batch (L·B query rows);
-        the grouped kernel matches each table's code rows against only that
+        The L tables' live codes are stacked as a single lane-dense device
+        array and L is folded into the query batch (L·B query rows); the
+        grouped kernel matches each table's code rows against only that
         table's query rows, so launch count is independent of L.
 
         NOTE the parameter split: ``l`` is the per-table scan depth (the
@@ -425,20 +571,25 @@ class MultiTableIndex:
         ids_topk/margins_topk are set when topk > 1 and always have
         exactly topk columns (impossible slots: id -1 / margin +inf).
         mask: optional bool mask over stable-id space restricting answers,
-        as in query_batch.  Returns a BatchQueryResult interchangeable with
-        the host-table query_batch path (candidates come back sorted by id
-        rather than in probe order); all reported ids are stable ids.
+        as in query_batch; it is read on the host at the deduplicated
+        candidates only (B·L·l entries), never over all rows.  Returns a
+        BatchQueryResult interchangeable with the host-table query_batch
+        path (candidates come back sorted by id rather than in probe
+        order); all reported ids are stable ids.
 
         Each stage runs under a host span on the profiler's clock:
         ``repro.hash`` (in ``batch_query.hash_queries_all``), then
         ``repro.scan``, ``repro.dedup``, ``repro.mask``, ``repro.rerank``,
-        one ``repro.fetch`` per blocking device-to-host read, and
-        ``repro.results`` for the host work after the reads.
+        one ``repro.fetch`` per blocking device-to-host read (two: the
+        candidates, then the answers) and ``repro.results`` for the host
+        work after the reads.
         """
         self._require_fit("query_scan_batch")
         w = np.atleast_2d(np.asarray(w, np.float32))
         b = w.shape[0]
-        if not self.active.any():
+        codes_dev, live_rows_dev = self._scan_state()
+        n_live = self._n_live
+        if n_live == 0:
             ids_pad = np.full((b, topk), -1, np.int64)
             m_pad = np.full((b, topk), np.inf, np.float32)
             return BatchQueryResult(
@@ -448,27 +599,33 @@ class MultiTableIndex:
                 np.zeros(self.num_tables, dtype=np.int64),
                 ids_topk=ids_pad if topk > 1 else None,
                 margins_topk=m_pad if topk > 1 else None)
-        codes_dev, live_rows_dev = self._scan_state(mesh, shard_axis)
-        n_live = self._live_rows.shape[0]
-        w_dev = jnp.asarray(w)          # one upload for hash and re-rank
+        w_dev = self._put(w)            # one upload for hash and re-rank
         qcodes = bq.hash_queries_all(
-            self.families, w_dev, use_kernels=self.config.use_kernels)
-        _, idx = self._scan(codes_dev, qcodes, l, n_live, mesh, shard_axis)
+            self.families, w_dev, use_kernels=self.config.use_kernels,
+            mesh=self.mesh)
+        _, idx = self._scan(codes_dev, qcodes, l, n_live)
         with TraceAnnotation("repro.dedup"):
-            grows, uniq, hits = bq.dedup_candidates(idx, live_rows_dev)
+            grows, packed = bq.dedup_packed(idx, live_rows_dev,
+                                            self.active.size)
+        packed = _fetch(packed)
+        cand, hits = packed[:-1], packed[-1, :self.num_tables]
         # mask narrows answers/rerank, but (as in the probe path) NOT the
         # reported candidate short-lists — backends stay interchangeable.
         with TraceAnnotation("repro.mask"):
-            mask_rows = self.mask_to_rows(mask)
-            valid = uniq if mask_rows is None else (
-                bq.mask_candidates(uniq, grows, mask_rows))
+            valid = cand >= 0
+            if mask is not None:
+                ids = self.rows_to_ids(np.maximum(cand, 0))
+                valid &= np.asarray(mask, dtype=bool)[ids]
+            valid_dev = self._put(valid)
         with TraceAnnotation("repro.rerank"):
-            margins, top = margin_rerank_batch(self.x, w_dev, grows, valid,
-                                               topk)
-        margins, top = _fetch(margins), _fetch(top)
-        hits = _fetch(hits).astype(np.int64)
-        grows_np, valid_np = _fetch(grows), _fetch(valid)
-        uniq_np = _fetch(uniq)
+            if self.mesh is None:
+                answer = margin_rerank_batch(self.x, w_dev, grows, valid_dev,
+                                             topk, rows_minor=self._x_minor)
+            else:
+                answer = sharded_rerank(self._x_dev, w_dev, grows, valid_dev,
+                                        topk, self.mesh, self.axis,
+                                        rows_minor=self._x_minor)
+        margins, top = _fetch(answer)
         with TraceAnnotation("repro.results"):
             top = top.astype(np.int64)
             top[~np.isfinite(margins)] = -1
@@ -477,31 +634,33 @@ class MultiTableIndex:
                 margins = np.pad(margins, padw, constant_values=np.inf)
                 top = np.pad(top, padw, constant_values=-1)
             top = self.rows_to_ids(top)
-            cands = [self.rows_to_ids(grows_np[i, uniq_np[i]])
-                     for i in range(b)]
+            cands = [self.rows_to_ids(c[c >= 0]) for c in cand]
             return BatchQueryResult(
-                top[:, 0], margins[:, 0], valid_np.any(axis=1), cands, hits,
+                top[:, 0], margins[:, 0], valid.any(axis=1), cands,
+                hits.astype(np.int64),
                 ids_topk=top if topk > 1 else None,
                 margins_topk=margins if topk > 1 else None)
 
-    def _scan(self, codes_dev, qcodes, l: int, n_live: int, mesh,
-              shard_axis: str):
+    def _scan(self, codes_dev, qcodes, l: int, n_live: int):
         """Per-table Hamming top-l of the stacked live codes, (dists, idx)
-        each (L, B, l) on device, under the ``repro.scan`` span: sharded
-        over ``mesh``, through the fused kernel, or in plain jnp."""
+        each (L, B, l) on device, under the ``repro.scan`` span: shard by
+        shard over the features' mesh, through the fused kernel, or in
+        plain jnp."""
         select = self.config.fused_select       # None -> REPRO_FUSED_SELECT
         pack = self.config.cand_pack            # None -> REPRO_CAND_PACK
         with TraceAnnotation("repro.scan"):
-            if mesh is not None:
-                return hamming_topk_grouped_sharded(
-                    codes_dev, qcodes, l, mesh, axis=shard_axis,
-                    use_kernel=self.config.use_kernels, n_valid=n_live,
-                    select=select, pack=pack)
+            if self.mesh is not None:
+                return sharded_topk_lanes(
+                    codes_dev, qcodes, l, self.mesh, self.axis,
+                    self._n_local, self._active_dev,
+                    use_kernel=self.config.use_kernels, select=select,
+                    pack=pack)
             if self.config.use_kernels:
                 from repro.kernels import ops
-                return ops.hamming_topk_grouped(codes_dev, qcodes, l,
-                                                select=select, pack=pack)
-            return hamming_topk_grouped(codes_dev, qcodes, l, select=select)
+                return ops.hamming_topk_lanes(codes_dev, qcodes, l, n_live,
+                                              select=select, pack=pack)
+            return hamming_topk_grouped(_rows(codes_dev, n_live), qcodes, l,
+                                        select=select)
 
     # -- replicated-shard serving hooks (serving.cluster) --------------------
     #
@@ -511,8 +670,7 @@ class MultiTableIndex:
     # exactly the pieces the router needs: the pre-merge per-table top-l in
     # stable-id space, and per-candidate margins with no selection.
 
-    def scan_table_topk(self, w, l: int = 16, mesh=None,
-                        shard_axis: str = "data"
+    def scan_table_topk(self, w, l: int = 16
                         ) -> tuple[np.ndarray, np.ndarray]:
         """Per-table Hamming top-l surfaced PRE-merge, in stable-id space.
 
@@ -526,19 +684,21 @@ class MultiTableIndex:
         self._require_fit("scan_table_topk")
         w = np.atleast_2d(np.asarray(w, np.float32))
         b = w.shape[0]
-        if not self.active.any():
+        codes_dev, _ = self._scan_state()
+        n_live = self._n_live
+        if n_live == 0:
             return (np.full((self.num_tables, b, l), DIST_SENTINEL,
                             np.int32),
                     np.full((self.num_tables, b, l), -1, np.int64))
-        codes_dev, live_rows_dev = self._scan_state(mesh, shard_axis)
-        n_live = self._live_rows.shape[0]
         qcodes = bq.hash_queries_all(
-            self.families, w, use_kernels=self.config.use_kernels)
-        dists, idx = self._scan(codes_dev, qcodes, l, n_live, mesh,
-                                shard_axis)
+            self.families, self._put(w), use_kernels=self.config.use_kernels,
+            mesh=self.mesh)
+        dists, idx = self._scan(codes_dev, qcodes, l, n_live)
         idx_np = np.asarray(idx, dtype=np.int64)
-        grows = np.asarray(self._live_rows)[np.clip(idx_np, 0, n_live - 1)]
-        ids = np.where(idx_np >= 0, self.ids_np[grows], -1)
+        grows = np.maximum(idx_np, 0)
+        if self._live_rows is not None:
+            grows = self._live_rows[grows]
+        ids = self.rows_to_ids(np.where(idx_np >= 0, grows, -1))
         return np.asarray(dists, dtype=np.int32), ids
 
     def candidate_margins(self, w, cand_ids: np.ndarray) -> np.ndarray:
@@ -558,7 +718,8 @@ class MultiTableIndex:
         cand_ids = np.asarray(cand_ids, dtype=np.int64)
         known = (cand_ids >= 0) & (cand_ids < self._next_id)
         rows = np.zeros(cand_ids.shape, dtype=np.int64)
-        rows[known] = self._row_of[cand_ids[known]]
+        rows[known] = (cand_ids[known] if self._row_of is None
+                       else self._row_of[cand_ids[known]])
         valid = known & (rows >= 0)
         rows[~valid] = 0
         m = margin_batch(self.x, jnp.asarray(w, jnp.float32),
@@ -567,7 +728,7 @@ class MultiTableIndex:
 
     def stats(self) -> dict:
         per_table = [t.stats() for t in self.tables]
-        rows = self.active.size if self.active is not None else 0
+        rows = self.active.size - self._pads if self.active is not None else 0
         return {
             "tables": self.num_tables,
             "n": self.n,
@@ -581,6 +742,8 @@ class MultiTableIndex:
             "device_uploads": self.device_uploads,
             "scan_state_rebuilds": self.scan_state_rebuilds,
             "compaction_steps": self.compaction_steps,
+            "shards": 1 if self.mesh is None else self.mesh.shape[self.axis],
+            "rows_per_shard": self._n_local,
             "per_table": per_table,
             "buckets_total": int(sum(s["buckets"] for s in per_table)),
         }

@@ -20,8 +20,9 @@ Two interchangeable backends (``mode``):
 - ``"scan"`` — the device-resident fused top-k Hamming scan
   (``MultiTableIndex.query_scan_batch``): one kernel launch for all L
   tables and the whole micro-batch, no host tables and no candidate cache.
-  With ``mesh=``, the scan runs row-sharded over the mesh axis — one local
-  launch per shard, answers bit-identical to the single-device scan.
+  Over an index fitted on row-sharded features, the scan runs one local
+  launch per shard and each shard re-ranks the rows it owns, answers
+  bit-identical to the single-device scan.
 
 Scan depth (``scan_l``) trades recall for rerank cost.  Under the default
 histogram selection (``IndexConfig.fused_select`` / REPRO_FUSED_SELECT =
@@ -51,17 +52,11 @@ class HashQueryService:
 
     def __init__(self, index: MultiTableIndex, max_batch: int | None = None,
                  cache_size: int = 1024, mode: str = "probe",
-                 scan_l: int = 16, mesh=None, shard_axis: str = "data"):
+                 scan_l: int = 16):
         assert mode in ("probe", "scan"), mode
-        assert mesh is None or mode == "scan", "mesh requires mode='scan'"
         self.index = index
         self.mode = mode
         self.scan_l = int(scan_l)
-        # scan-mode row sharding: the index lays its stacked live codes out
-        # over this mesh axis and answers each micro-batch with one local
-        # launch per shard (core.search.hamming_topk_grouped_sharded)
-        self.mesh = mesh
-        self.shard_axis = shard_axis
         self.max_batch = int(max_batch if max_batch is not None
                              else index.config.batch)
         assert self.max_batch >= 1
@@ -254,9 +249,7 @@ class HashQueryService:
         device-bound — there is no host probe work to save)."""
         t_start = time.perf_counter()
         b = ws.shape[0]
-        res = self.index.query_scan_batch(ws, l=self.scan_l, mask=mask,
-                                          mesh=self.mesh,
-                                          shard_axis=self.shard_axis)
+        res = self.index.query_scan_batch(ws, l=self.scan_l, mask=mask)
         elapsed = time.perf_counter() - t_start
         self.last_coverage = float(getattr(res, "coverage", 1.0))
         if getattr(res, "degraded", False):

@@ -71,15 +71,36 @@ def test_sharded_hamming_topk():
 @pytest.mark.parametrize("shards", [2, 4])
 @pytest.mark.parametrize("use_kernel", [True, False])
 def test_sharded_grouped_hamming_topk(shards, use_kernel):
-    """hamming_topk_grouped_sharded == the single-device grouped scan, bit
-    for bit: even and ragged shard sizes, ties across shard boundaries,
+    """sharded_topk_lanes == the single-device grouped scan, bit for bit:
+    even and ragged shard sizes (a ragged table's last shards carry dead
+    padding rows, flagged by ``active``), ties across shard boundaries,
     and l > n sentinels surviving the shard offset."""
     out = _run(f"""
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.search import (DIST_SENTINEL, hamming_topk_grouped,
-                                       hamming_topk_grouped_sharded)
+                                       lane_rows, sharded_topk_lanes,
+                                       to_lanes)
         uk = {use_kernel}
-        mesh = jax.make_mesh(({shards},), ("data",))
+        shards = {shards}
+        mesh = jax.make_mesh((shards,), ("data",))
+
+        def scan(codes, qs, l):
+            g, n, w = codes.shape
+            n_local = -(-n // shards)
+            codes = np.pad(codes, ((0, 0), (0, shards * n_local - n), (0, 0)))
+            lanes = jnp.concatenate(
+                [to_lanes(jnp.asarray(codes[:, s * n_local:][:, :n_local]),
+                          lane_rows(n_local)) for s in range(shards)], axis=2)
+            lanes = jax.device_put(
+                lanes, NamedSharding(mesh, P(None, None, "data", None)))
+            active = None
+            if shards * n_local > n:
+                active = jax.device_put(np.arange(shards * n_local) < n,
+                                        NamedSharding(mesh, P("data")))
+            return sharded_topk_lanes(lanes, jnp.asarray(qs), l, mesh,
+                                      "data", n_local, active, use_kernel=uk)
+
         rng = np.random.default_rng(0)
         cases = [(3, 512, 4, 2, 16),    # even shards
                  (2, 1001, 3, 2, 8),    # ragged: 1001 rows over shards
@@ -90,8 +111,7 @@ def test_sharded_grouped_hamming_topk(shards, use_kernel):
             qs = rng.integers(0, 2**32, (g, b, w), dtype=np.uint32)
             dw, iw = hamming_topk_grouped(jnp.asarray(codes),
                                           jnp.asarray(qs), l)
-            dg, ig = hamming_topk_grouped_sharded(
-                jnp.asarray(codes), jnp.asarray(qs), l, mesh, use_kernel=uk)
+            dg, ig = scan(codes, qs, l)
             assert np.array_equal(np.asarray(dg), np.asarray(dw)), (g, n, l)
             assert np.array_equal(np.asarray(ig), np.asarray(iw)), (g, n, l)
             if l > n:   # sentinel tail intact after the offset/merge
@@ -101,8 +121,7 @@ def test_sharded_grouped_hamming_topk(shards, use_kernel):
         codes = np.zeros((2, 103, 2), np.uint32)
         qs = rng.integers(0, 2**32, (2, 3, 2), dtype=np.uint32)
         dw, iw = hamming_topk_grouped(jnp.asarray(codes), jnp.asarray(qs), 60)
-        dg, ig = hamming_topk_grouped_sharded(
-            jnp.asarray(codes), jnp.asarray(qs), 60, mesh, use_kernel=uk)
+        dg, ig = scan(codes, qs, 60)
         assert np.array_equal(np.asarray(ig), np.asarray(iw))
         assert np.array_equal(np.asarray(dg), np.asarray(dw))
         print("ok")
@@ -111,42 +130,91 @@ def test_sharded_grouped_hamming_topk(shards, use_kernel):
 
 
 def test_sharded_query_scan_batch():
-    """MultiTableIndex.query_scan_batch(mesh=) == the single-device scan,
-    before and after delete churn + auto-compaction, and through the
-    scan-mode service."""
+    """MultiTableIndex fitted on row-sharded features (its mesh read from
+    x.sharding) == the one-device index on the same rows: query_scan_batch,
+    scan_table_topk and the scan-mode service, three tables, before and
+    after delete churn and auto-compaction — which leaves the shards
+    ragged (each keeps its own live rows, the fuller shards set the block
+    size and the others pad it with dead rows); stable ids survive."""
     out = _run("""
         import jax, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.indexer import IndexConfig
         from repro.data.synthetic import tiny1m_like
         from repro.serving import HashQueryService, MultiTableIndex
         corpus = tiny1m_like(n_labeled=700, n_unlabeled=0, d=32, classes=5,
                              seed=0)
-        x = corpus.x[:597]                           # 597 rows: ragged shards
+        x = corpus.x[:600]                           # 150 rows a shard
         rng = np.random.default_rng(1)
         ws = rng.normal(size=(8, x.shape[1])).astype(np.float32)
         mesh = jax.make_mesh((4,), ("data",))
+        xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))
         cfg = IndexConfig(method="bh", bits=18, tables=3)
-        mt = MultiTableIndex(cfg).fit(x)
-        a = mt.query_scan_batch(ws, l=16, topk=4)
-        b = mt.query_scan_batch(ws, l=16, topk=4, mesh=mesh)
-        assert np.array_equal(a.ids, b.ids)
-        assert np.array_equal(a.margins, b.margins)
-        assert np.array_equal(a.ids_topk, b.ids_topk)
-        assert np.array_equal(a.margins_topk, b.margins_topk)
-        for i in range(8):
-            assert np.array_equal(a.candidates[i], b.candidates[i])
-        # 50%+ delete churn triggers auto-compaction; sharded still matches
-        mt.delete(np.arange(299))                    # 299/597 > 0.5
-        assert mt.compactions == 1, mt.compactions
-        a = mt.query_scan_batch(ws, l=16)
-        b = mt.query_scan_batch(ws, l=16, mesh=mesh)
-        assert np.array_equal(a.ids, b.ids)
-        assert (a.ids >= 299).all()                  # stable ids survive
-        svc = HashQueryService(mt, max_batch=8, mode="scan", scan_l=16,
-                               mesh=mesh)
+        one = MultiTableIndex(cfg).fit(x)
+        mt = MultiTableIndex(cfg).fit(xs)
+        assert (mt.mesh, mt.axis) == (mesh, "data")
+        assert mt.stats()["shards"] == 4
+        assert mt.stats()["rows_per_shard"] == 150
+
+        def same(mask=None):
+            a = one.query_scan_batch(ws, l=16, topk=4, mask=mask)
+            b = mt.query_scan_batch(ws, l=16, topk=4, mask=mask)
+            assert np.array_equal(a.ids, b.ids)
+            assert np.array_equal(a.margins, b.margins)
+            assert np.array_equal(a.ids_topk, b.ids_topk)
+            assert np.array_equal(a.margins_topk, b.margins_topk)
+            assert np.array_equal(a.table_hits, b.table_hits)
+            for i in range(8):
+                assert np.array_equal(a.candidates[i], b.candidates[i])
+            for got, want in zip(mt.scan_table_topk(ws, l=16),
+                                 one.scan_table_topk(ws, l=16)):
+                assert np.array_equal(got, want)
+            return b
+
+        same()
+        # tombstones: a delete flags rows dead on their chip
+        gone = rng.choice(600, 200, replace=False)
+        one.delete(gone)
+        mt.delete(gone)
+        assert mt.compactions == 0 and mt.n == 400
+        b = same()
+        assert not np.isin(b.ids, gone).any()
+        same(rng.random(600) < 0.5)
+        # past half dead, auto-compaction: the shards keep different live
+        # counts, so all but the fullest carry dead padding rows
+        more = rng.choice(np.setdiff1d(np.arange(600), gone), 110,
+                          replace=False)
+        one.delete(more)
+        mt.delete(more)
+        assert mt.compactions == 1 == one.compactions, mt.compactions
+        live = np.setdiff1d(np.arange(600), np.concatenate([gone, more]))
+        per_shard = np.bincount(live // 150, minlength=4)
+        assert len(set(per_shard.tolist())) > 1, per_shard
+        assert mt.stats()["rows_per_shard"] == per_shard.max()
+        assert mt.n == 290 and mt.stats()["rows"] == 290
+        b = same()
+        assert np.isin(b.ids[b.nonempty], live).all()    # stable ids
+        same(rng.random(600) < 0.5)
+        assert np.array_equal(mt.compact(), live)        # nothing dead: no-op
+        try:
+            mt.delete(gone[:1])
+            raise AssertionError("deleted a compacted-away id")
+        except KeyError:
+            pass
+        one.delete(live[:5])
+        mt.delete(live[:5])
+        same()
+        svc = HashQueryService(mt, max_batch=8, mode="scan", scan_l=16)
         got = svc.query_batch(ws)
-        assert [r.index for r in got] == b.ids.tolist()
+        assert [r.index for r in got] == (
+            one.query_scan_batch(ws, l=16).ids.tolist())
         assert svc.stats()["requests"] == 8
+        for op, arg in (("insert", x[:2]), ("lookup_batch", ws)):
+            try:
+                getattr(mt, op)(arg)
+            except NotImplementedError:
+                continue
+            raise AssertionError(op)
         print("ok")
     """, devices=4)
     assert "ok" in out

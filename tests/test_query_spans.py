@@ -16,7 +16,7 @@ from repro.serving import HashQueryService, MultiTableIndex
 
 STAGES = ("repro.hash", "repro.scan", "repro.dedup", "repro.mask",
           "repro.rerank", "repro.fetch", "repro.results")
-READS = 6      # margins, top, hits, grows, valid, uniq
+READS = 2      # the candidates with the hits; margins and ids
 
 
 def _repro_spans(trace_dir):

@@ -209,23 +209,24 @@ def test_scan_single_launch_any_tables(corpus, queries, monkeypatch):
     """query_scan_batch issues exactly ONE Hamming scan dispatch no matter
     how many tables the index holds (L folds into the query batch).  The
     dispatch target depends on the backend (core.search's jnp path with
-    use_kernels off, kernels.ops with it on), so count both."""
+    use_kernels off, kernels.ops's scan of the lane-dense codes with it
+    on), so count both."""
     import repro.kernels.ops as kops
     import repro.serving.multi_table as mtb
     calls = {"n": 0}
     real = mtb.hamming_topk_grouped
-    real_ops = kops.hamming_topk_grouped
+    real_ops = kops.hamming_topk_lanes
 
     def counting(codes, qs, l, **kw):
         calls["n"] += 1
         return real(codes, qs, l, **kw)
 
-    def counting_ops(codes, qs, l, **kw):
+    def counting_ops(codes, qs, l, n, **kw):
         calls["n"] += 1
-        return real_ops(codes, qs, l, **kw)
+        return real_ops(codes, qs, l, n, **kw)
 
     monkeypatch.setattr(mtb, "hamming_topk_grouped", counting)
-    monkeypatch.setattr(kops, "hamming_topk_grouped", counting_ops)
+    monkeypatch.setattr(kops, "hamming_topk_lanes", counting_ops)
     for L in (1, 4):
         mt = MultiTableIndex(_cfg(tables=L)).fit(corpus.x)
         calls["n"] = 0

@@ -47,11 +47,12 @@ def one_chip(topo):
 
 def _scan_shapes(w: int, masked: bool):
     """The operand shapes ops.hamming_topk_grouped hands the fused kernels
-    for one table and a C-query batch."""
+    for one table and a C-query batch: lane-dense codes."""
     b = -(-C // ops.SUBLANE) * ops.SUBLANE
     bn = ops._block_rows(N, 4096, b)
     n_pad = -(-N // bn) * bn
-    shapes = [((1, n_pad, w), jnp.uint32), ((1, b, w), jnp.uint32)]
+    shapes = [((1, w, n_pad // 128, 128), jnp.uint32),
+              ((1, b, w), jnp.uint32)]
     if masked:
         shapes.append(((1, n_pad), jnp.int32))
     return bn, shapes
